@@ -277,8 +277,19 @@ def sharded_child(seconds: float, rpd: int, repeats: int, out: str):
 
 
 def run_sharded_comparison(seconds: float, rpd: int, repeats: int) -> dict:
-    """Spawn the 8-device child (XLA_FLAGS must precede jax init there)."""
+    """Spawn the 8-device child (XLA_FLAGS must precede jax init there).
+    Forcing host devices only works on the CPU backend, and on an
+    accelerator this process already holds the devices the child would
+    need, so there the comparison is refused."""
     import tempfile
+
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return {"error": f"the sharded comparison forces 8 CPU devices in "
+                         f"a child process; this process holds the "
+                         f"{jax.default_backend()!r} devices — rerun with "
+                         f"JAX_PLATFORMS=cpu or --no-sharded"}
 
     out = os.path.join(tempfile.mkdtemp(prefix="spreeze_bench_"),
                        "sharded.json")

@@ -77,7 +77,7 @@ def _megastep_arm(mesh, *, prioritized: bool, capacity: int,
     compiled = tr._megastep.lower(tr.state, tr.replay, tr.env_states,
                                   tr.key).compile()
     hlo = compiled.as_text()
-    cost = analysis.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = analysis.collective_bytes(hlo)
     roof = analysis.Roofline(
         arch="spreeze_megastep",
@@ -151,23 +151,22 @@ def megastep_report(out: str) -> bool:
 
 def run_megastep(out: str) -> bool:
     """Entry for --megastep: in-process when the host already has >= 8
-    devices, else a child process with the count forced (the flag must
-    precede jax initialization). The child is marked via env so a
-    backend the flag cannot grow (it only affects the CPU platform —
-    e.g. a 4-GPU host) errors out instead of forking forever."""
+    devices, else a child process with 8 CPU devices forced (the flag
+    must precede jax initialization). The flag only grows the CPU
+    platform, and a parent on an accelerator holds its devices, so a
+    child there could not start: that case is refused."""
     import jax
 
     if len(jax.devices()) >= 8:
         return megastep_report(out)
-    if os.environ.get("SPREEZE_ROOFLINE_CHILD"):
+    if jax.default_backend() != "cpu":
         raise RuntimeError(
-            f"forced 8 host devices but the {jax.default_backend()!r} "
-            f"backend still exposes {len(jax.devices())} — "
-            "xla_force_host_platform_device_count only grows the CPU "
-            "platform; run on >= 8 devices or on the CPU backend")
+            f"the megastep roofline needs 8 devices; this "
+            f"{jax.default_backend()!r} host has {len(jax.devices())}, and "
+            "forcing host devices only works on the CPU backend — run "
+            "with JAX_PLATFORMS=cpu")
     from benchmarks.common import child_pythonpath, xla_flags_force_devices
     env = dict(os.environ, PYTHONPATH=child_pythonpath(),
-               SPREEZE_ROOFLINE_CHILD="1",
                XLA_FLAGS=xla_flags_force_devices(8))
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.roofline", "--megastep",

@@ -127,8 +127,8 @@ def _replay_add_batch():
     from repro.replay import buffer
 
     state = buffer.init_replay(256, buffer.specs_for_env(3, 1))
-    batch = {k: jnp.ones((8,) + v.shape[1:], v.dtype)
-             for k, v in state.data.items()}
+    batch = {k: jnp.ones((8,) + shape, jnp.float32)
+             for k, shape in state.layout}
     # a FRESH keyed-jit wrapper: the module-level lru cache may already
     # hold traces from earlier work in this process, which would
     # pollute the retrace probe

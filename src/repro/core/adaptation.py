@@ -116,6 +116,8 @@ def probe_replay(obs_dim: int, act_dim: int, cap: int, gamma: float, key
     the trainer always adds a ``"disc"`` row (so the timed HLO must not
     take the ``batch.get("disc", ...)`` fallback path) and ``done`` is a
     {0,1} indicator, not a normal sample."""
+    import dataclasses
+
     import jax.numpy as jnp
 
     from repro.replay import buffer as rb
@@ -126,8 +128,9 @@ def probe_replay(obs_dim: int, act_dim: int, cap: int, gamma: float, key
             for i, (k, (s, d)) in enumerate(specs.items())}
     fill["done"] = (fill["done"] > 0).astype(jnp.float32)
     fill["disc"] = gamma * (1.0 - fill["done"])
-    return rb.ReplayState(data=fill, ptr=jnp.zeros((), jnp.int32),
-                          size=jnp.asarray(cap, jnp.int32))
+    replay = rb.init_replay(cap, specs)
+    return dataclasses.replace(replay, rows=rb.pack_rows(replay.layout, fill),
+                               size=jnp.asarray(cap, jnp.int32))
 
 
 def auto_tune(env_name: str = "pendulum", algo: str = "sac", *,

@@ -18,6 +18,7 @@ backbone towers (RLHF-scale Spreeze).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -256,12 +257,11 @@ def make_arch_spreeze_losses(cfg: ModelConfig, act_dim: int = 16,
 # ---------------------------------------------------------------------------
 
 def replay_sharding(replay, rules: MeshRules):
-    """NamedSharding pytree for the replay ring: every (capacity, ...)
-    leaf shards its rows over the ``batch`` axis (each group owns a slice
-    of the pool; scatter/gather stay group-local under GSPMD), the ring
-    bookkeeping scalars replicate. Handles both the uniform
+    """NamedSharding pytree for the replay ring: the (capacity, width)
+    record array shards its rows over the ``batch`` axis (each group owns
+    a slice of the pool; scatter/gather stay group-local under GSPMD), the
+    ring bookkeeping scalars replicate. Handles both the uniform
     ``ReplayState`` and the PER ``PrioritizedState`` wrapper."""
-    from repro.replay.buffer import ReplayState
     rep = NamedSharding(rules.mesh, P())
     if hasattr(replay, "base"):            # PrioritizedState
         from repro.replay.prioritized import PrioritizedState
@@ -269,5 +269,6 @@ def replay_sharding(replay, rules: MeshRules):
             base=replay_sharding(replay.base, rules),
             priorities=NamedSharding(rules.mesh, P(rules.batch)),
             max_priority=rep)
-    return ReplayState(data=batch_sharding(replay.data, rules),
-                       ptr=rep, size=rep)
+    return dataclasses.replace(
+        replay, rows=NamedSharding(rules.mesh, P(rules.batch, None)),
+        ptr=rep, size=rep)

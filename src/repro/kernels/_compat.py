@@ -1,21 +1,12 @@
-"""jax version-compat shims + backend probes for Pallas TPU.
-
-The compiler-params dataclass was renamed upstream
-(``TPUCompilerParams`` -> ``CompilerParams``); resolve whichever this
-jax ships so the kernels run on both sides of the rename.
-"""
+"""Backend probe for the Pallas kernels' ``interpret`` switch."""
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
 
 def interpret_default() -> bool:
     """Pallas ``interpret`` switch resolved from the backend at trace
     time: compile to Mosaic on TPU, run the interpreter everywhere else
-    (this CPU container, CI). Kernel entry points take ``interpret=None``
-    and resolve through here, so no call site hardcodes ``True``."""
+    (CPU tests, CI). Kernel entry points take ``interpret=None`` and
+    resolve through here, so no call site hardcodes ``True``."""
     return jax.default_backend() != "tpu"
 
 

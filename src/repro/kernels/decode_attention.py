@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -101,7 +101,7 @@ def decode_attention(q, k_cache, v_cache, valid_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(vl, q, k_cache, v_cache)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -122,7 +122,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=resolve_interpret(interpret),

@@ -25,7 +25,6 @@ import functools
 from typing import Optional, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlolint.contract import (CollectiveContract,
@@ -129,8 +128,8 @@ def priority_scatter(priorities, idx, values) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("alpha", "k"))
 def per_topk(priorities, gumbel, alpha: float, k: int):
-    """Fused PER score + top-k selection — the (capacity,) score vector
-    never materializes. -> (scores (k,), global indices (k,))."""
+    """PER score kernel + top-k selection. -> (scores (k,), global
+    indices (k,), priorities at the indices (k,))."""
     return _replay.per_topk(priorities, gumbel, alpha, k)
 
 
@@ -151,7 +150,7 @@ RING_GATHER_COLLECTIVES = (
 )
 PER_TOPK_COLLECTIVES = (
     # the (groups*k,) candidate merge — the ONLY cross-group PER traffic
-    # (score and index gathers, one all-gather each)
+    # (score, index and priority gathers, one all-gather each)
     CollectiveRule("all-gather", ("groups*k",)),
 )
 
@@ -187,9 +186,9 @@ def ring_write_sharded(data, batch, ptr, rules: MeshRules) -> jax.Array:
         lo = batch_group_index(rules) * rows_local
         return _replay.ring_write(d, b, p, capacity=cap, window_start=lo)
 
-    return shard_map(local, mesh=rules.mesh,
-                     in_specs=(spec, P(), P()), out_specs=spec,
-                     check_rep=False)(data, batch, ptr)
+    return jax.shard_map(local, mesh=rules.mesh,
+                         in_specs=(spec, P(), P()), out_specs=spec,
+                         check_vma=False)(data, batch, ptr)
 
 
 def ring_gather_sharded(data, idx, rules: MeshRules) -> jax.Array:
@@ -211,9 +210,9 @@ def ring_gather_sharded(data, idx, rules: MeshRules) -> jax.Array:
         return jax.lax.psum_scatter(part, axes, scatter_dimension=0,
                                     tiled=True)
 
-    return shard_map(local, mesh=rules.mesh,
-                     in_specs=(spec, P()), out_specs=spec,
-                     check_rep=False)(data, idx)
+    return jax.shard_map(local, mesh=rules.mesh,
+                         in_specs=(spec, P()), out_specs=spec,
+                         check_vma=False)(data, idx)
 
 
 def per_scores_sharded(priorities, gumbel, alpha: float,
@@ -226,17 +225,17 @@ def per_scores_sharded(priorities, gumbel, alpha: float,
     def local(p, g):
         return _replay.per_scores(p, g, alpha)
 
-    return shard_map(local, mesh=rules.mesh,
-                     in_specs=(spec, spec), out_specs=spec,
-                     check_rep=False)(priorities, gumbel)
+    return jax.shard_map(local, mesh=rules.mesh,
+                         in_specs=(spec, spec), out_specs=spec,
+                         check_vma=False)(priorities, gumbel)
 
 
 def per_topk_sharded(priorities, gumbel, alpha: float, k: int,
                      rules: MeshRules):
     """Mesh-native two-phase PER selection (the ROADMAP's RDMA-local
-    sampling): each batch group runs the fused ``per_topk`` kernel on
-    its local priority shard (window offset = its first global ring
-    slot) and emits k candidates ``(score, global_idx)``; an
+    sampling): each batch group runs ``per_topk`` (score kernel + top-k)
+    on its local priority shard (window offset = its first global ring
+    slot) and emits k candidates ``(score, global_idx, priority)``; an
     ``all_gather`` of the ``(groups * k,)`` candidates over the batch
     axes — the ONLY cross-group traffic, never capacity-proportional —
     feeds the fixed-group-order merge, which every group evaluates
@@ -253,14 +252,15 @@ def per_topk_sharded(priorities, gumbel, alpha: float, k: int,
 
     def local(p, g):
         lo = batch_group_index(rules) * rows_local
-        s, i = _replay.per_topk(p, g, alpha, k, window_start=lo)
-        cs = jax.lax.all_gather(s, axes, axis=0, tiled=True)
-        ci = jax.lax.all_gather(i, axes, axis=0, tiled=True)
-        return _replay.merge_topk_candidates(cs, ci, k)
+        cand = _replay.per_topk(p, g, alpha, k, window_start=lo)
+        return _replay.merge_topk_candidates(
+            *(jax.lax.all_gather(c, axes, axis=0, tiled=True)
+              for c in cand), k)
 
-    return shard_map(local, mesh=rules.mesh,
-                     in_specs=(spec, spec), out_specs=(P(), P()),
-                     check_rep=False)(priorities, gumbel)
+    return jax.shard_map(local, mesh=rules.mesh,
+                         in_specs=(spec, spec),
+                         out_specs=(P(), P(), P()),
+                         check_vma=False)(priorities, gumbel)
 
 
 def priority_scatter_sharded(priorities, idx, values,
@@ -277,6 +277,6 @@ def priority_scatter_sharded(priorities, idx, values,
         lo = batch_group_index(rules) * rows_local
         return _replay.priority_scatter(p, i, v, window_start=lo)
 
-    return shard_map(local, mesh=rules.mesh,
-                     in_specs=(spec, P(), P()), out_specs=spec,
-                     check_rep=False)(priorities, idx, values)
+    return jax.shard_map(local, mesh=rules.mesh,
+                         in_specs=(spec, P(), P()), out_specs=spec,
+                         check_vma=False)(priorities, idx, values)

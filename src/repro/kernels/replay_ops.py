@@ -19,26 +19,28 @@ rows into blocks and pipeline the HBM<->VMEM traffic with
   double-buffers the VMEM out tiles); within a block the random row
   fetches run as a depth-``GATHER_DEPTH`` window of in-flight HBM->VMEM
   DMAs instead of issue-wait-issue-wait.
-* ``per_scores`` — blocked elementwise pass producing the Gumbel-top-k
-  sampling scores for the PER pool (empty slots masked to a true -inf).
-  Kept as the bench baseline for ``per_topk`` (score pass + a global
-  ``jax.lax.top_k`` over the materialized score vector).
-* ``per_topk`` — the fused score + selection kernel: each block's
-  Gumbel scores are computed in VMEM and folded into a running top-k
-  held in a VMEM scratch of size k (a vectorized sorted insert: the
-  block is concatenated with the running buffer and re-selected, with
-  a threshold guard skipping blocks that cannot contribute), so the
-  globally-assembled ``(capacity,)`` score vector never exists in HBM.
-  Under ``shard_map`` each batch group emits its local k candidates
-  ``(score, global_idx)`` and ``merge_topk_candidates`` reduces the
-  ``(groups * k,)`` gathered candidates — selection is group-local and
-  the only cross-group PER traffic is k candidates per group, never
-  anything proportional to capacity. Because the merge runs in a fixed
-  group order with stable ties, the two-phase selection is exactly the
-  dense ``top_k`` on live rows: PER draws are layout-invariant across
-  mesh shapes (see ``replay.prioritized``).
-* ``priority_scatter`` — scatter of new |TD|+eps priorities at the
-  sampled (arbitrary) indices.
+* ``per_scores`` — blocked elementwise pass over lane-dense
+  ``(rows, 128)`` tiles producing the Gumbel-top-k sampling scores for
+  the PER pool (empty slots masked to a true -inf).
+* ``per_topk`` — PER selection: the ``per_scores`` kernel, then XLA's
+  ``top_k`` over the window's scores (Pallas TPU has no ``top_k``
+  lowering). Under ``shard_map`` each batch group emits its local k
+  candidates ``(score, global_idx, priority)`` and
+  ``merge_topk_candidates`` reduces the ``(groups * k,)`` gathered
+  candidates — selection is group-local and the only cross-group PER
+  traffic is k candidates per group, never anything proportional to
+  capacity. Because the merge runs in a fixed group order with stable
+  ties, the two-phase selection is exactly the dense ``top_k`` on live
+  rows: PER draws are layout-invariant across mesh shapes (see
+  ``replay.prioritized``).
+* ``priority_scatter`` — the new |TD|+eps priorities at the sampled
+  (arbitrary) indices, as a blocked read-modify-write of the priority
+  vector, which stays in HBM.
+
+Mosaic moves only lane-aligned slices, so a row DMA moves one whole
+128-lane row: the ring kernels run on the replay's packed
+``(capacity, 128)`` records (``replay.buffer``), and the PER kernels
+view the ``(capacity,)`` priority vector as ``(capacity / 128, 128)``.
 
 Every kernel takes a **window**: the operand may be a shard covering
 global ring slots ``[window_start, window_start + local_rows)`` of a
@@ -46,10 +48,9 @@ global ring slots ``[window_start, window_start + local_rows)`` of a
 (write/scatter) or zero-filled (gather — the shard_map wrapper in
 ``kernels.ops`` combines the partial gathers with a ``psum_scatter``).
 With the default window (the whole pool) the kernels are the
-single-device fast path; under an active ``("ac","batch")`` mesh
+single-device path; under an active ``("ac","batch")`` mesh
 ``kernels.ops`` wraps them in ``shard_map`` so each batch group runs the
-kernel on its local ring shard — no more jnp fallback under active mesh
-rules.
+kernel on its local ring shard.
 
 ``interpret`` resolves from the backend at trace time (``None`` ->
 interpreter off on TPU, on elsewhere); the ``*_ref`` functions are the
@@ -76,6 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels._compat import resolve_interpret
 
 BLOCK_ROWS = 128      # default rows per DMA block (f32 sublane-friendly)
+LANES = 128           # f32 lanes of one vreg row
 GATHER_DEPTH = 8      # in-flight row DMAs per gather block
 
 TRACE_COUNTS: collections.Counter = collections.Counter()
@@ -222,10 +224,10 @@ def ring_write(data: jax.Array, batch: jax.Array, ptr, *,
                           rows_local=rows_local, blk=blk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(d2.shape, d2.dtype),
         input_output_aliases={2: 0},
         interpret=resolve_interpret(interpret),
@@ -322,7 +324,7 @@ def ring_gather(data: jax.Array, idx: jax.Array, *, window_start=0,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((blk, nfeat), lambda b, info, idx: (b, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((depth,))])
     out = pl.pallas_call(
@@ -366,42 +368,52 @@ def _per_scores_kernel(pri_ref, g_ref, out_ref, *, alpha: float):
     out_ref[...] = per_scores_ref(pri_ref[...], g_ref[...], alpha)
 
 
+def _lane_dense(x: jax.Array, rows_per_block: int) -> jax.Array:
+    """(n,) -> (R, LANES) zero-padded, R a multiple of ``rows_per_block``
+    (a reshape of the 1-D tiled layout, so no copy when n divides)."""
+    pad = (-x.shape[0]) % (rows_per_block * LANES)
+    return (jnp.pad(x, (0, pad)) if pad else x).reshape(-1, LANES)
+
+
+def _block_rows(n: int, block: int) -> int:
+    """Rows of a (rows, LANES) grid block over an n-element vector: the
+    whole vector when it fits ``block`` elements, else a multiple of the
+    8-row sublane tile."""
+    r = pl.cdiv(n, LANES)
+    return r if r * LANES <= block else max(8, block // LANES // 8 * 8)
+
+
 def per_scores(priorities: jax.Array, gumbel: jax.Array, alpha: float, *,
-               block: int = 1024,
+               block: int = 64 * 1024,
                interpret: Optional[bool] = None) -> jax.Array:
     """Blocked elementwise pass over the (rows,) priority vector -> the
-    Gumbel-top-k sampling scores (see ``per_scores_ref``). The caller
-    runs ``top_k`` on the result; under shard_map each group scores its
-    local priority shard."""
+    Gumbel-top-k sampling scores (see ``per_scores_ref``), on lane-dense
+    (block_rows, 128) tiles. Under shard_map each group scores its local
+    priority shard."""
     TRACE_COUNTS["per_scores"] += 1
     (rows,) = priorities.shape
-    blk = max(128, min(block, rows))
-    pad = (-rows) % blk
-    p2 = jnp.pad(priorities, (0, pad)) if pad else priorities
-    g2 = jnp.pad(gumbel, (0, pad)) if pad else gumbel
-    nb = p2.shape[0] // blk
-    p2, g2 = p2.reshape(nb, blk), g2.reshape(nb, blk)
+    br = _block_rows(rows, block)
+    p2, g2 = _lane_dense(priorities, br), _lane_dense(gumbel, br)
+    spec = pl.BlockSpec((br, LANES), lambda b: (b, 0))
     out = pl.pallas_call(
         functools.partial(_per_scores_kernel, alpha=alpha),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, blk), lambda b: (b, 0)),
-                  pl.BlockSpec((1, blk), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((1, blk), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, blk), jnp.float32),
+        grid=(p2.shape[0] // br,),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(p2.shape, jnp.float32),
         interpret=resolve_interpret(interpret),
     )(p2, g2)
-    return out.reshape(nb * blk)[:rows]
+    return out.reshape(-1)[:rows]
 
 
 # --------------------------------------------------------------------------- #
-# PER: fused score + top-k selection (group-local index selection)
+# PER: score kernel + top-k selection (group-local index selection)
 # --------------------------------------------------------------------------- #
 
-# Index carried by top-k slots that hold no real row (score -inf): the
-# running buffer's initial fill, and block-padding lanes. Among equal
-# -inf scores the selected index is unspecified (callers cycle the live
-# draws and never dereference a -inf slot — ``replay.prioritized``), so
-# the sentinel only has to stay out of the live index range.
+# Index carried by top-k slots that hold no real row (score -inf). Among
+# equal -inf scores the selected index is unspecified (callers cycle the
+# live draws and never dereference a -inf slot — ``replay.prioritized``),
+# so the sentinel only has to stay out of the live index range.
 IDX_SENTINEL = 2**31 - 1
 
 
@@ -409,16 +421,17 @@ def per_topk_ref(priorities: jax.Array, gumbel: jax.Array, alpha: float,
                  k: int, *, window_start=0):
     """jnp oracle for ``per_topk``: dense Gumbel-top-k over the window.
 
-    Returns ``(scores (k,), global_idx (k,))`` sorted by descending
-    score. Indices of -inf entries (fewer than k live rows in the
-    window) are real here but a sentinel in the kernel — compare them
-    only where the score is finite."""
+    Returns ``(scores (k,), global_idx (k,), priorities (k,))`` sorted by
+    descending score. Indices of -inf entries (fewer than k live rows in
+    the window) are real here but a sentinel in the kernel path — compare
+    them only where the score is finite."""
     v, i = jax.lax.top_k(per_scores_ref(priorities, gumbel, alpha), k)
-    return v, (i + jnp.asarray(window_start, jnp.int32)).astype(jnp.int32)
+    return (v, (i + jnp.asarray(window_start, jnp.int32)).astype(jnp.int32),
+            jnp.take(priorities, i))
 
 
 def merge_topk_candidates(cand_scores: jax.Array, cand_idx: jax.Array,
-                          k: int):
+                          cand_pri: jax.Array, k: int):
     """Reduce ``(groups * k,)`` per-group candidates to the global top-k.
 
     The candidate vectors MUST be concatenated in the fixed batch-group
@@ -427,156 +440,106 @@ def merge_topk_candidates(cand_scores: jax.Array, cand_idx: jax.Array,
     ``top_k``'s stable ties the merge returns exactly the dense top-k
     over the whole pool, which is what makes PER draws layout-invariant:
     the global top-k is always a subset of the union of per-group
-    top-k's, so no candidate the merge needs can be missing."""
+    top-k's, so no candidate the merge needs can be missing. Each
+    candidate's priority rides along for the importance weights."""
     v, pos = jax.lax.top_k(cand_scores, k)
-    return v, jnp.take(cand_idx, pos)
-
-
-def _per_topk_kernel(scal_ref, pri_ref, gum_ref, outs_ref, outi_ref, *,
-                     alpha: float, k: int, rows: int, blk: int):
-    """Streaming top-k over the (nb, blk)-blocked priority/gumbel pair.
-
-    The running top-k lives in the (1, k) VMEM outputs; per block the
-    scores are computed in VMEM from the double-buffered block loads
-    and folded in with a vectorized sorted insert (concat + re-select).
-    A threshold guard (block max vs the current k-th best) skips the
-    insert for blocks that cannot change the result — on a warm buffer
-    most blocks only pay the elementwise score pass."""
-    nb = pri_ref.shape[0]
-    lo = scal_ref[0]
-    outs_ref[...] = jnp.full((1, k), -jnp.inf, jnp.float32)
-    outi_ref[...] = jnp.full((1, k), IDX_SENTINEL, jnp.int32)
-
-    def body(scratch, sems):
-        def fetch(slot, b):
-            return (pltpu.make_async_copy(pri_ref.at[pl.ds(b, 1), :],
-                                          scratch.at[slot, 0],
-                                          sems.at[slot, 0]),
-                    pltpu.make_async_copy(gum_ref.at[pl.ds(b, 1), :],
-                                          scratch.at[slot, 1],
-                                          sems.at[slot, 1]))
-
-        for cp in fetch(0, 0):
-            cp.start()
-
-        def loop(b, carry):
-            slot = jax.lax.rem(b, 2)
-
-            @pl.when(b + 1 < nb)
-            def _prefetch():        # overlap next fetch with this fold
-                for cp in fetch(jax.lax.rem(b + 1, 2), b + 1):
-                    cp.start()
-
-            for cp in fetch(slot, b):
-                cp.wait()
-            p, g = scratch[slot, 0], scratch[slot, 1]
-            lane = (jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
-                    + b * blk)
-            valid = lane < rows          # block-padding lanes are dead
-            s = jnp.where(valid, per_scores_ref(p, g, alpha), -jnp.inf)
-            gidx = jnp.where(valid, lane + lo, IDX_SENTINEL)
-
-            @pl.when(jnp.max(s) > outs_ref[0, k - 1])
-            def _fold():                 # sorted insert, vectorized:
-                cs = jnp.concatenate([outs_ref[...], s], axis=1)
-                ci = jnp.concatenate([outi_ref[...], gidx], axis=1)
-                v, pos = jax.lax.top_k(cs, k)
-                outs_ref[...] = v
-                outi_ref[...] = jnp.take_along_axis(ci, pos, axis=1)
-            return carry
-
-        jax.lax.fori_loop(0, nb, loop, 0)
-
-    pl.run_scoped(
-        body,
-        scratch=pltpu.VMEM((2, 2, 1, blk), jnp.float32),
-        sems=pltpu.SemaphoreType.DMA((2, 2)))
+    return v, jnp.take(cand_idx, pos), jnp.take(cand_pri, pos)
 
 
 def per_topk(priorities: jax.Array, gumbel: jax.Array, alpha: float,
-             k: int, *, window_start=0, block: int = 4096,
+             k: int, *, window_start=0, block: int = 64 * 1024,
              interpret: Optional[bool] = None):
-    """Fused PER selection: Gumbel-top-k scores + running top-k in one
-    blocked pass over the (rows,) priority window.
+    """PER selection over the (rows,) priority window: the ``per_scores``
+    kernel computes the Gumbel-top-k scores, XLA's ``top_k`` selects
+    (Pallas TPU has no ``top_k`` lowering).
 
-    Returns ``(scores (k,), global_idx (k,))`` — the window's k best
-    live candidates, indices offset by ``window_start`` so each mesh
-    group emits globally-addressed candidates for the cross-group merge
-    (``merge_topk_candidates``). Matches ``per_topk_ref`` exactly on
-    every finite-score slot; -inf slots carry ``IDX_SENTINEL`` (their
-    index is unspecified and unused — draws past the live-row count
-    cycle the live draws)."""
+    Returns ``(scores (k,), global_idx (k,), priorities (k,))`` — the
+    window's k best live candidates, indices offset by ``window_start``
+    so each mesh group emits globally-addressed candidates for the
+    cross-group merge (``merge_topk_candidates``). Matches
+    ``per_topk_ref`` exactly on every finite-score slot; -inf slots
+    carry ``IDX_SENTINEL`` (their index is unspecified and unused —
+    draws past the live-row count cycle the live draws)."""
     (rows,) = priorities.shape
     if k > rows:
         raise ValueError(f"per_topk of k={k} from a {rows}-row window")
     TRACE_COUNTS["per_topk"] += 1
-    blk = max(128, min(block, rows))
-    pad = (-rows) % blk
-    p2 = jnp.pad(priorities, (0, pad)) if pad else priorities
-    g2 = jnp.pad(gumbel, (0, pad)) if pad else gumbel
-    nb = p2.shape[0] // blk
-    p2, g2 = p2.reshape(nb, blk), g2.reshape(nb, blk)
-    outs, outi = pl.pallas_call(
-        functools.partial(_per_topk_kernel, alpha=alpha, k=k, rows=rows,
-                          blk=blk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
-                   pl.BlockSpec(memory_space=pltpu.VMEM)),
-        out_shape=(jax.ShapeDtypeStruct((1, k), jnp.float32),
-                   jax.ShapeDtypeStruct((1, k), jnp.int32)),
-        interpret=resolve_interpret(interpret),
-    )(jnp.asarray(window_start, jnp.int32).reshape(1), p2, g2)
-    return outs.reshape(k), outi.reshape(k)
+    v, i = jax.lax.top_k(per_scores(priorities, gumbel, alpha, block=block,
+                                    interpret=interpret), k)
+    gidx = i + jnp.asarray(window_start, jnp.int32)
+    return (v, jnp.where(v > -jnp.inf, gidx, IDX_SENTINEL),
+            jnp.take(priorities, i))
 
 
-def _priority_scatter_kernel(lo_ref, idx_ref, val_ref, pri_ref, out_ref, *,
-                             k: int, rows_local: int):
+def _priority_scatter_kernel(lo_ref, idx_ref, val_ref, pri_ref, out_ref,
+                             buf, sem, *, k: int, rows_local: int, br: int):
+    """Blocked read-modify-write of the lane-dense (R, LANES) priority
+    vector, which stays in HBM: each (br, LANES) block is DMA'd into
+    VMEM, every in-window update landing in it is applied as a masked
+    lane store on its row, and the block is DMA'd back. Updates apply
+    in index order, so a repeated slot keeps its last value."""
     del pri_ref                     # aliased with out_ref
     lo = lo_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
-    def row(i, carry):
-        dest = idx_ref[i] - lo
+    def block(b, carry):
+        rows = pl.ds(b * br, br)
+        cp = pltpu.make_async_copy(out_ref.at[rows, :], buf, sem.at[0])
+        cp.start()
+        cp.wait()
 
-        @pl.when((dest >= 0) & (dest < rows_local))
-        def _write():
-            out_ref[pl.ds(jnp.clip(dest, 0, rows_local - 1), 1), :] = (
-                jnp.full((1, 1), val_ref[i], out_ref.dtype))
+        def one(i, c):
+            slot = idx_ref[i] - lo
+            d = slot - b * (br * LANES)
+
+            @pl.when((slot < rows_local) & (d >= 0) & (d < br * LANES))
+            def _write():
+                r = pl.ds(d // LANES, 1)
+                buf[r, :] = jnp.where(lane == d % LANES, val_ref[i],
+                                      buf[r, :])
+            return c
+
+        jax.lax.fori_loop(0, k, one, 0)
+        cp = pltpu.make_async_copy(buf, out_ref.at[rows, :], sem.at[0])
+        cp.start()
+        cp.wait()
         return carry
 
-    jax.lax.fori_loop(0, k, row, 0)
+    jax.lax.fori_loop(0, out_ref.shape[0] // br, block, 0)
 
 
 def priority_scatter(priorities: jax.Array, idx: jax.Array,
                      values: jax.Array, *, window_start=0,
+                     block: int = 256 * 1024,
                      interpret: Optional[bool] = None) -> jax.Array:
     """``priorities[idx - window_start] = values`` for the in-window
     subset of the (arbitrary, PER-sampled) indices; out-of-window
-    updates are dropped (they belong to another group's shard). In place
-    via aliasing when the caller donates the priority vector."""
+    updates are dropped (they belong to another group's shard). The
+    vector stays in HBM and is updated in place via aliasing when the
+    caller donates it (and its length is a multiple of the block)."""
     TRACE_COUNTS["priority_scatter"] += 1
     (rows_local,) = priorities.shape
     k = idx.shape[0]
+    br = _block_rows(rows_local, block)
+    p2 = _lane_dense(priorities, br)
     out = pl.pallas_call(
         functools.partial(_priority_scatter_kernel, k=k,
-                          rows_local=rows_local),
+                          rows_local=rows_local, br=br),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows_local, 1), jnp.float32),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(p2.shape, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((br, LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((1,))],
         input_output_aliases={3: 0},
         interpret=resolve_interpret(interpret),
     )(jnp.asarray(window_start, jnp.int32).reshape(1),
-      idx.astype(jnp.int32), values.astype(jnp.float32),
-      priorities.reshape(rows_local, 1))
-    return out.reshape(rows_local)
+      idx.astype(jnp.int32), values.astype(jnp.float32), p2)
+    return out.reshape(-1)[:rows_local]
 
 
 def priority_scatter_ref(priorities: jax.Array, idx: jax.Array,

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -50,7 +50,7 @@ def rmsnorm(x, weight, *, eps: float = 1e-6, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=resolve_interpret(interpret),
     )(x2, weight)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_scr, *,
@@ -103,7 +103,7 @@ def ssd_scan(x, dtA, B_, C_, *, chunk: int = 64,
             jax.ShapeDtypeStruct((Bb, H, P, N), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(x, dtA, B_, C_)

@@ -1,11 +1,12 @@
 """Compiled-artifact analysis: collective bytes + roofline terms.
 
-The roofline (EXPERIMENTS.md §Roofline) is derived from the dry-run's
-compiled artifact, not from wall time (this container is CPU-only):
+The roofline (EXPERIMENTS.md §Roofline) is derived from a compiled
+artifact, not from wall time, against the peaks of the target chip
+(``PEAKS``, keyed by ``device_kind``):
 
-  compute term    = HLO_FLOPs   / (chips x 197 TFLOP/s bf16)
-  memory term     = HLO_bytes   / (chips x 819 GB/s HBM)
-  collective term = coll_bytes  / (chips x 50 GB/s ICI per link)
+  compute term    = HLO_FLOPs   / peak bf16 FLOP/s
+  memory term     = HLO_bytes   / peak HBM bytes/s
+  collective term = coll_bytes  / ICI bytes/s per link
 
 ``cost_analysis()`` reports the *per-partition* (per-device) module under
 GSPMD, so its flops/bytes are NOT divided by the chip count again; the
@@ -21,7 +22,7 @@ here unchanged for existing callers (``benchmarks/roofline.py``,
 from __future__ import annotations
 
 from dataclasses import asdict, dataclass
-from typing import Dict
+from typing import Dict, NamedTuple
 
 # Shared HLO parsing (moved to repro.analysis.hlolint.hlo in PR 8;
 # re-exported here for back-compat — the private names too, since the
@@ -37,20 +38,33 @@ from repro.analysis.hlolint.hlo import (  # noqa: F401
     scan_trip_counts,
 )
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
+class DevicePeaks(NamedTuple):
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # B/s
+    ici_bw: float               # B/s per link
 
 
-def cost_dict(compiled) -> Dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: newer
-    jax returns a dict, older a one-element list of per-computation
-    dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. TPU v5e: Google
+#: Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+#: 1,600 Gbit/s of interchip interconnect per chip over 4 links
+#: (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bw=819e9,
+                               ici_bw=50e9),
+}
+
+#: the chip the dry-run and the roofline probe target
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """The peaks of ``device_kind``; a device missing from ``PEAKS`` is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak rates for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
 
 
 @dataclass
@@ -70,11 +84,13 @@ class Roofline:
     useful_ratio: float = 0.0
     peak_memory_per_device: float = 0.0
     notes: str = ""
+    device_kind: str = TARGET_DEVICE_KIND
 
     def finalize(self) -> "Roofline":
-        self.compute_s = self.flops_per_device / PEAK_FLOPS_BF16
-        self.memory_s = self.bytes_per_device / HBM_BW
-        self.collective_s = self.collective_bytes_per_device / ICI_BW
+        pk = peaks(self.device_kind)
+        self.compute_s = self.flops_per_device / pk.flops_bf16
+        self.memory_s = self.bytes_per_device / pk.hbm_bw
+        self.collective_s = self.collective_bytes_per_device / pk.ici_bw
         terms = {"compute": self.compute_s, "memory": self.memory_s,
                  "collective": self.collective_s}
         self.bottleneck = max(terms, key=terms.get)

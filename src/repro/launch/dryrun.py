@@ -89,7 +89,7 @@ def _probe_costs(cfg, shape, rules, periods: int, *,
     with unroll_scans():
         lowered = _lower_for(rc, rules)
         compiled = lowered.compile()
-    cost = analysis.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = analysis.collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -163,7 +163,7 @@ def lower_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
             c2 = _probe_costs(cfg, shape, rules, 2, fsdp=fsdp)
             costs = _extrapolate(c1, c2, _n_periods(cfg))
         else:
-            cost = analysis.cost_dict(compiled)
+            cost = compiled.cost_analysis()
             coll = analysis.collective_bytes(compiled.as_text())
             costs = {"flops": float(cost.get("flops", 0.0)),
                      "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -273,7 +273,7 @@ def lower_spreeze(*, multi_pod: bool = True, algo: str = "sac",
         lowered = jax.jit(update_fn, in_shardings=in_sh,
                           donate_argnums=(0,)).lower(state, batch, key)
         compiled = lowered.compile()
-    cost = analysis.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = analysis.collective_bytes(compiled.as_text())
     return {"mode": "spreeze_rl_update", "algo": algo, "mesh": mesh_name,
             "batch_size": batch_size, "placement": placement,
@@ -351,7 +351,7 @@ def lower_spreeze_arch(arch: str, *, batch: int = 32, seq: int = 1024,
                                     rew, done, key)
         compiled = lowered.compile()
 
-    cost = analysis.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = analysis.collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
     return {"mode": "spreeze_arch_update", "arch": arch, "mesh": "2x16x16",
@@ -419,7 +419,7 @@ def lower_spreeze_sampler(*, env_name: str = "pendulum",
                 actor, states, key).compile()
 
     coll = analysis.collective_bytes(compiled.as_text())
-    cost = analysis.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     return {"mode": "spreeze_sampler", "env": env_name,
             "num_envs": num_envs, "chunk_len": chunk_len, "mesh": "2x16x16",
             "flops_per_device": float(cost.get("flops", 0.0)),

@@ -1,5 +1,11 @@
 """Production meshes for the dry-run (TPU v5e pods; host-CPU placeholders).
 
+Every mesh here is built with ``AxisType.Auto`` axes: the trainer and
+the model stack are written for GSPMD propagation (sharding constraints
+plus ``shard_map`` islands), and ``jax.make_mesh`` otherwise builds
+``Explicit`` axes, under which a scatter into a batch-sharded ring
+raises ``ShardingTypeError``.
+
 A FUNCTION, not a module constant, so importing this never touches jax
 device state — the dry-run entry point sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
@@ -10,7 +16,11 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto(n: int):
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -32,7 +42,8 @@ def make_production_mesh(*, multi_pod: bool = False,
             f"{len(devs)}; run under "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py sets this automatically)")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=_auto(len(axes)))
 
 
 def make_ac_mesh(ac: int = 2, batch: int = 0) -> Mesh:
@@ -50,7 +61,8 @@ def make_ac_mesh(ac: int = 2, batch: int = 0) -> Mesh:
             f"need {need} devices for an {ac}x{batch} ac mesh, found "
             f"{len(devs)}; run under XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need}")
-    return jax.make_mesh((ac, batch), ("ac", "batch"), devices=devs[:need])
+    return jax.make_mesh((ac, batch), ("ac", "batch"), devices=devs[:need],
+                         axis_types=_auto(2))
 
 
 def parse_ac_mesh(spec: str) -> Mesh:
@@ -84,4 +96,4 @@ def make_debug_mesh(data: int = 1, model: int = 1) -> Optional[Mesh]:
     if len(devs) < n:
         return None
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devs[:n])
+                         devices=devs[:n], axis_types=_auto(2))

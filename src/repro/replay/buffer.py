@@ -6,14 +6,26 @@ a **donated pytree living in HBM**: ``add`` is a jitted scatter into the
 ring (in-place thanks to buffer donation) and ``sample`` a jitted gather,
 so experience never leaves the accelerator and neither side "dumps" data.
 
+Row format: every transition is ONE packed float32 record of
+``RECORD_LANES`` lanes (the fields side by side, zero-padded), so the
+ring is a single ``(capacity, RECORD_LANES)`` array. That is the shape
+the TPU's tiled HBM layout and a Pallas row DMA both want: Mosaic only
+moves lane-aligned slices, and a separate ``(capacity, 3)`` leaf would
+be relaid out into a 128-lane temporary around every kernel call
+(``tests/test_tpu_compile.py`` checks the ring's bytes with
+``memory_analysis()``). A write is one scatter and a sample one gather
+of whole records; ``ReplayState.data`` unpacks the named fields.
+
 Batch sharding: rows are laid out over the ``batch`` logical axis, so on a
 mesh each data-parallel group owns a slice of the pool — the multi-pod
 generalization of one shared-RAM pool per desktop.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Dict, NamedTuple, Tuple
+import math
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,20 +81,69 @@ def _per_select_mode(cap_rows: int, k: int) -> str:
     return mode
 
 
-class ReplayState(NamedTuple):
-    data: Dict[str, jax.Array]     # each (capacity, ...) leaf
+#: lanes of one packed ring record: one float32 vreg row, the unit a
+#: ring kernel moves with a single DMA
+RECORD_LANES = 128
+
+#: static record layout: ((field name, row shape), ...) in lane order
+Layout = Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+
+def record_width(layout: Layout) -> int:
+    used = sum(math.prod(shape) for _, shape in layout)
+    return RECORD_LANES * max(1, -(-used // RECORD_LANES))
+
+
+def pack_rows(layout: Layout, fields: Dict[str, jax.Array]) -> jax.Array:
+    """{name: (n, *shape)} -> (n, record_width) float32 records."""
+    n = fields[layout[0][0]].shape[0]
+    cols = [fields[name].reshape(n, -1).astype(jnp.float32)
+            for name, _ in layout]
+    used = sum(c.shape[1] for c in cols)
+    cols.append(jnp.zeros((n, record_width(layout) - used), jnp.float32))
+    return jnp.concatenate(cols, axis=1)
+
+
+def unpack_rows(layout: Layout, rows: jax.Array) -> Dict[str, jax.Array]:
+    """(n, record_width) records -> {name: (n, *shape)}."""
+    out, off = {}, 0
+    for name, shape in layout:
+        w = math.prod(shape)
+        out[name] = rows[:, off:off + w].reshape((rows.shape[0],) + shape)
+        off += w
+    return out
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("rows", "ptr", "size"),
+                   meta_fields=("layout",))
+@dataclasses.dataclass(frozen=True)
+class ReplayState:
+    rows: jax.Array                # (capacity, record_width) f32 records
     ptr: jax.Array                 # int32 next write slot
     size: jax.Array                # int32 filled rows
+    layout: Layout                 # static: field order and row shapes
+
+    @property
+    def data(self) -> Dict[str, jax.Array]:
+        """The ring's named fields, each (capacity, *row_shape)."""
+        return unpack_rows(self.layout, self.rows)
 
 
 def init_replay(capacity: int, specs: Dict[str, Tuple[Tuple[int, ...],
                                                       jnp.dtype]]
                 ) -> ReplayState:
-    """specs: name -> (row_shape, dtype). E.g. {"obs": ((3,), f32), ...}."""
-    data = {k: jnp.zeros((capacity,) + tuple(s), d)
-            for k, (s, d) in specs.items()}
-    return ReplayState(data=data, ptr=jnp.zeros((), jnp.int32),
-                       size=jnp.zeros((), jnp.int32))
+    """specs: name -> (row_shape, dtype). E.g. {"obs": ((3,), f32), ...}.
+    Every field is float32: the fields share one packed record."""
+    for k, (_, d) in specs.items():
+        if jnp.dtype(d) != jnp.float32:
+            raise ValueError(f"replay field {k!r} is {jnp.dtype(d)}; "
+                             f"ring records are float32")
+    layout = tuple((k, tuple(s)) for k, (s, _) in specs.items())
+    return ReplayState(
+        rows=jnp.zeros((capacity, record_width(layout)), jnp.float32),
+        ptr=jnp.zeros((), jnp.int32), size=jnp.zeros((), jnp.int32),
+        layout=layout)
 
 
 def specs_for_env(obs_dim: int, act_dim: int):
@@ -114,6 +175,13 @@ def write_plan(ptr, n: int, cap: int):
     return (ptr + drop) % cap, n - drop
 
 
+def ring_slots_mask(cap: int, ptr0, n: int) -> jax.Array:
+    """(cap,) bool: the slots an n-row write starting at ``ptr0`` lands
+    on — an elementwise mask, so a per-slot vector next to the ring (the
+    PER priorities) is written without a scatter."""
+    return (jnp.arange(cap, dtype=jnp.int32) - ptr0) % cap < n
+
+
 def scatter_rows(dest: jax.Array, rows: jax.Array, ptr0) -> jax.Array:
     """dest[(ptr0 + i) % cap] = rows via the blocked Pallas ring kernel
     (shard_map'd onto the mesh under active rules) or the jnp scatter,
@@ -143,31 +211,28 @@ def add_batch(state: ReplayState, batch: Dict[str, jax.Array]) -> ReplayState:
     """Scatter N new rows at (ptr + i) % capacity. Jit with donated state —
     the write happens in place in HBM (shared-memory semantics). See
     ``write_plan`` for oversized-write handling."""
-    any_leaf = next(iter(batch.values()))
-    n = any_leaf.shape[0]
-    cap = next(iter(state.data.values())).shape[0]
+    new = pack_rows(state.layout, batch)
+    n = new.shape[0]
+    cap = state.rows.shape[0]
     ptr0, keep = write_plan(state.ptr, n, cap)
-    if keep < n:
-        batch = {k: v[n - keep:] for k, v in batch.items()}
-    # pin the ring leaves to the batch axis so GSPMD never un-shards the
-    # pool across a megastep's scan carries (no-op without active rules)
-    data = {k: shard(scatter_rows(state.data[k], batch[k], ptr0),
-                     *(("batch",) + (None,) * (state.data[k].ndim - 1)))
-            for k in state.data}
-    return ReplayState(data=data,
-                       ptr=(state.ptr + n) % cap,
-                       size=jnp.minimum(state.size + n, cap))
+    # pin the ring to the batch axis so GSPMD never un-shards the pool
+    # across a megastep's scan carries (no-op without active rules)
+    rows = shard(scatter_rows(state.rows, new[n - keep:], ptr0),
+                 "batch", None)
+    return dataclasses.replace(state, rows=rows,
+                               ptr=(state.ptr + n) % cap,
+                               size=jnp.minimum(state.size + n, cap))
 
 
 def sample(state: ReplayState, key, batch_size: int) -> Dict[str, jax.Array]:
     """Uniform random gather of ``batch_size`` rows (with replacement —
     the paper's large-batch regime has batch >> new-experience rate)."""
-    cap = next(iter(state.data.values())).shape[0]
+    cap = state.rows.shape[0]
     idx = jax.random.randint(key, (batch_size,), 0,
                              jnp.maximum(state.size, 1))
     # ring alignment: the oldest live row sits at ptr when full
     idx = (idx + jnp.where(state.size >= cap, state.ptr, 0)) % cap
-    return {k: gather_rows(v, idx) for k, v in state.data.items()}
+    return unpack_rows(state.layout, gather_rows(state.rows, idx))
 
 
 def _pallas_keyed_jit(fn):

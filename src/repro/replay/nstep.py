@@ -36,7 +36,7 @@ def nstep_chunk(exps: Dict[str, jax.Array], n: int, gamma: float
         pad = jnp.zeros((i,) + a.shape[1:], a.dtype)
         return jnp.concatenate([a[i:], pad], axis=0)
 
-    for i in range(1, n):
+    for i in range(1, min(n, T)):         # steps past the chunk add 0
         valid = (jnp.arange(T) + i < T).astype(rew.dtype)  # (T,)
         valid = valid.reshape((T,) + (1,) * (rew.ndim - 1))
         take = cont * valid                  # rows still accumulating

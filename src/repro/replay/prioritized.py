@@ -24,10 +24,10 @@ phantom mass of empty capacity slots.
 Two-phase selection (group-local PER): index selection routes through
 ``_select`` in every mode — each batch group (one group when meshless)
 runs a top-k over its OWN priority shard and only ``(groups * k,)``
-candidate pairs cross the batch axis for the merge; the globally
-assembled ``(capacity,)`` score vector never exists. Under ``use_pallas``
-the per-group pass is the fused ``per_topk`` kernel (score + running
-top-k in one blocked VMEM pass) shard_map'd over the mesh batch axes
+candidates cross the batch axis for the merge; no globally assembled
+``(capacity,)`` score vector exists. Under ``use_pallas`` the per-group
+pass is ``per_topk`` (the ``per_scores`` kernel + ``top_k``)
+shard_map'd over the mesh batch axes
 (``kernels.ops.per_topk_sharded``); the jnp oracle is the dense
 ``per_topk_ref`` (two-phase with a single group — bit-identical, since
 the merge in fixed group order with stable ties IS the dense top-k over
@@ -35,9 +35,10 @@ live rows). That identity is the layout-invariance guarantee: given the
 same pool state and key, PER draws the same batch on (1,1), (1,8), or
 (2,4) meshes, pallas or jnp (``jax_threefry_partitionable`` keeps the
 Gumbel noise itself layout-invariant). The re-prioritization scatter and
-the importance-weight gather are likewise group-local — no PER op moves
-capacity-proportional data across groups (``benchmarks/roofline.py``
-asserts it on the lowered HLO).
+the importance-weight priorities (carried with the candidates) are
+likewise group-local — no PER op moves capacity-proportional data
+across groups (``benchmarks/roofline.py`` asserts it on the lowered
+HLO).
 """
 from __future__ import annotations
 
@@ -50,7 +51,8 @@ from repro.distributed.sharding import current_rules, shard
 from repro.kernels import ops as kops
 from repro.replay.buffer import (ReplayState, _pallas_keyed_jit,
                                  _per_select_mode, _ring_mode, gather_rows,
-                                 init_replay, scatter_rows, write_plan)
+                                 init_replay, ring_slots_mask, unpack_rows,
+                                 write_plan)
 
 
 class PrioritizedState(NamedTuple):
@@ -75,9 +77,8 @@ def add_batch(state: PrioritizedState, batch: Dict[str, jax.Array]
     # same ring slots as base_add's data write, incl. oversized-write drop
     ptr0, keep = write_plan(state.base.ptr, n, cap)
     # priorities live row-aligned with the data: same batch-axis shard
-    pri = shard(scatter_rows(state.priorities,
-                             jnp.broadcast_to(state.max_priority, (keep,)),
-                             ptr0), "batch")
+    pri = shard(jnp.where(ring_slots_mask(cap, ptr0, keep),
+                          state.max_priority, state.priorities), "batch")
     return PrioritizedState(base=base_add(state.base, batch),
                             priorities=pri,
                             max_priority=state.max_priority)
@@ -85,11 +86,12 @@ def add_batch(state: PrioritizedState, batch: Dict[str, jax.Array]
 
 def _select(priorities: jax.Array, gumbel: jax.Array, alpha: float,
             k: int):
-    """Two-phase PER index selection -> (scores (k,), indices (k,)).
+    """Two-phase PER index selection -> (scores (k,), indices (k,),
+    priorities at the indices (k,)).
 
     Phase 1 is group-local: each batch group top-k's its own priority
-    shard (the fused ``per_topk`` kernel under ``use_pallas``, the dense
-    ``per_topk_ref`` oracle otherwise). Phase 2 merges the
+    shard (``per_topk``'s score kernel + top-k under ``use_pallas``, the
+    dense ``per_topk_ref`` oracle otherwise). Phase 2 merges the
     ``(groups * k,)`` candidates in fixed group order
     (``merge_topk_candidates``) — with a single group the merge is the
     identity, so every mode computes the same dense top-k over live
@@ -126,24 +128,25 @@ def sample(state: PrioritizedState, key, batch_size: int, *,
     g = shard(-jnp.log(-jnp.log(
         jax.random.uniform(key, state.priorities.shape,
                            minval=1e-12, maxval=1.0))), "batch")
-    idx = _select(state.priorities, g, alpha, batch_size)[1]
+    _, idx, pri_sel = _select(state.priorities, g, alpha, batch_size)
     # every live row outranks every -inf empty slot, so draws past the
     # live count are garbage — wrap them onto the live draws
     live = state.priorities > 0.0
     n_live = jnp.maximum(jnp.sum(live.astype(jnp.int32)), 1)
-    idx = jnp.take(idx, jnp.arange(batch_size) % n_live)
-    batch = {k: gather_rows(v, idx) for k, v in state.base.data.items()}
+    draw = jnp.arange(batch_size) % n_live
+    idx, pri_sel = jnp.take(idx, draw), jnp.take(pri_sel, draw)
+    batch = unpack_rows(state.base.layout,
+                        gather_rows(state.base.rows, idx))
 
     # importance weights: w_i = (N * P(i))^-beta, normalized by max.
     # P(i) normalizes over the WRITTEN rows only — the 1e-12-floored
     # mass of empty capacity slots used to bias live-row weights
-    # whenever the pool wasn't full. The sampled rows' priority mass is
-    # fetched with the same group-local windowed gather as the data
-    # rows: indexing the sharded (capacity,) prob vector directly would
-    # make GSPMD all-gather it.
+    # whenever the pool wasn't full. The sampled rows' priorities ride
+    # along with the selection candidates: indexing the sharded
+    # (capacity,) vector directly would make GSPMD all-gather it.
     p = jnp.where(live, jnp.maximum(state.priorities, 1e-12) ** alpha, 0.0)
     z = jnp.maximum(jnp.sum(p), 1e-12)
-    p_sel = gather_rows(p.reshape(-1, 1), idx)[:, 0]
+    p_sel = jnp.maximum(pri_sel, 1e-12) ** alpha
     w = (n_live.astype(jnp.float32) * (p_sel / z)) ** (-beta)
     w = w / jnp.maximum(jnp.max(w), 1e-12)
     return batch, idx, w

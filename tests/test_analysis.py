@@ -121,6 +121,15 @@ def test_roofline_terms_and_bottleneck():
     assert r.useful_ratio == pytest.approx(0.5)
 
 
+def test_roofline_rejects_unknown_device_kind():
+    r = analysis.Roofline(arch="x", shape="y", mesh="m", chips=1,
+                          flops_per_device=1.0, bytes_per_device=1.0,
+                          collective_bytes_per_device=0.0,
+                          device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        r.finalize()
+
+
 def test_cost_analysis_is_per_partition():
     """GSPMD cost analysis reports the per-device module: sharding a
     matmul over N devices divides reported flops by ~N."""
@@ -132,8 +141,7 @@ def test_cost_analysis_is_per_partition():
     def f(a, b):
         return a @ b
 
-    from repro.launch.analysis import cost_dict
-    full = cost_dict(jax.jit(f).lower(x, x).compile())["flops"]
+    full = jax.jit(f).lower(x, x).compile().cost_analysis()["flops"]
     assert full == pytest.approx(2 * n ** 3, rel=0.1)
     # (single-device container: the sharded variant is exercised by the
     # dry-run; here we pin the unsharded reference the claim rests on)

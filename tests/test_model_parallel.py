@@ -10,12 +10,12 @@ from jax.sharding import PartitionSpec as P
 from repro.core.model_parallel import (make_arch_spreeze_losses,
                                        replay_sharding)
 from repro.distributed.sharding import trainer_rules
+from repro.launch.mesh import make_ac_mesh
 from repro.rl.base import AlgoHP
 
 
 def _ac_mesh():
-    return jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
+    return make_ac_mesh(1, 1)
 
 
 def test_trainer_rules_ac_placement():
@@ -39,11 +39,11 @@ def test_replay_sharding_specs():
     specs = rb.specs_for_env(3, 1)
     rep = rb.init_replay(64, specs)
     sh = replay_sharding(rep, rules)
-    assert sh.data["obs"].spec == P(("batch",), None)
-    assert sh.data["rew"].spec == P(("batch",))
+    assert sh.rows.spec == P(("batch",), None)
+    assert sh.layout == rep.layout
     assert sh.ptr.spec == P()
     psh = replay_sharding(per.init_prioritized(64, specs), rules)
-    assert psh.base.data["obs"].spec == P(("batch",), None)
+    assert psh.base.rows.spec == P(("batch",), None)
     assert psh.priorities.spec == P(("batch",))
     assert psh.max_priority.spec == P()
 

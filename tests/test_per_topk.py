@@ -14,6 +14,7 @@ from repro.distributed.sharding import trainer_rules, use_rules
 from repro.kernels import ops as kops
 from repro.kernels import replay_ops as rops
 from repro.kernels.ops import use_pallas
+from repro.launch.mesh import make_ac_mesh
 from repro.replay import buffer as rb
 from repro.replay import prioritized as per
 
@@ -22,12 +23,13 @@ def _check_selection(got, want):
     """Scores bit-exact; indices exact wherever the score is finite
     (-inf slots carry IDX_SENTINEL in the kernel — unspecified, and
     never dereferenced: ``sample`` cycles the live draws)."""
-    v, i = got
-    vr, ir = want
+    v, i, p = got
+    vr, ir, pr = want
     np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
     fin = np.isfinite(np.asarray(vr))
     np.testing.assert_array_equal(np.asarray(i)[fin], np.asarray(ir)[fin])
     assert (np.asarray(i)[~fin] == rops.IDX_SENTINEL).all()
+    np.testing.assert_array_equal(np.asarray(p)[fin], np.asarray(pr)[fin])
 
 
 @pytest.mark.parametrize("cap,live,k", [
@@ -74,8 +76,7 @@ def test_per_topk_window_merge_equals_global():
                           window_start=lo, block=128)
             for lo in range(0, cap, rows)]
     merged = rops.merge_topk_candidates(
-        jnp.concatenate([c[0] for c in cand]),
-        jnp.concatenate([c[1] for c in cand]), k)
+        *(jnp.concatenate([c[j] for c in cand]) for j in range(3)), k)
     _check_selection(merged, rops.per_topk_ref(pri, g, 0.6, k))
 
 
@@ -114,8 +115,7 @@ def _draws(mesh_shape=None, placement="ac", pallas=True, cap=64, bs=8):
         ctx.enter_context(use_pallas())
     if mesh_shape is not None:
         n = mesh_shape[0] * mesh_shape[1]
-        mesh = jax.make_mesh(mesh_shape, ("ac", "batch"),
-                             devices=jax.devices()[:n])
+        mesh = make_ac_mesh(*mesh_shape)
         ctx.enter_context(use_rules(trainer_rules(mesh, placement)))
     with ctx:
         st = per.init_prioritized(cap, rb.specs_for_env(2, 1))
@@ -164,8 +164,7 @@ def test_per_select_mode_dispatch():
     assert rb._per_select_mode(64, 8) == "jnp"
     with use_pallas():
         assert rb._per_select_mode(64, 8) == "pallas"
-        mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                             devices=jax.devices()[:1])
+        mesh = make_ac_mesh(1, 1)
         with use_rules(trainer_rules(mesh, "ac")):
             assert rb._per_select_mode(64, 8) == "shard"
             assert rb._per_select_mode(64, 64) == "shard"
@@ -175,8 +174,7 @@ def test_per_select_mode_dispatch():
 def test_per_select_mode_group_shard_too_small():
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a batch axis of size 2")
-    mesh = jax.make_mesh((1, 2), ("ac", "batch"),
-                         devices=jax.devices()[:2])
+    mesh = make_ac_mesh(1, 2)
     with use_pallas(), use_rules(trainer_rules(mesh, "ac")):
         assert rb._per_select_mode(64, 32) == "shard"
         assert rb._per_select_mode(64, 33) == "jnp"   # 33 > 64 // 2
@@ -189,8 +187,7 @@ def test_mesh_pallas_per_rejects_undersized_group_shard():
     from repro.core import SpreezeConfig, SpreezeTrainer
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a batch axis of size 2")
-    mesh = jax.make_mesh((1, 2), ("ac", "batch"),
-                         devices=jax.devices()[:2])
+    mesh = make_ac_mesh(1, 2)
     with pytest.raises(ValueError, match="group-local"):
         SpreezeTrainer(SpreezeConfig(
             env_name="pendulum", algo="sac", num_envs=2, batch_size=64,
@@ -207,9 +204,12 @@ def test_per_megastep_traces_per_topk_no_capacity_collective():
     from repro.core import SpreezeConfig, SpreezeTrainer
     from repro.launch.analysis import collective_result_shapes
 
-    mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
-    cap = 256
+    mesh = make_ac_mesh(1, 1)
+    # the ring gather's reduce-scatter is (batch, record lanes): the
+    # capacity must exceed batch * RECORD_LANES for an element count to
+    # tell batch-sized traffic from capacity-sized traffic
+    cap = 8192
+    assert cap > 32 * rb.RECORD_LANES
     cfg = SpreezeConfig(env_name="pendulum", algo="sac", num_envs=2,
                         batch_size=32, chunk_len=4, updates_per_round=2,
                         warmup_frames=32, replay_capacity=cap,

@@ -13,6 +13,7 @@ from repro.distributed.sharding import (standard_rules, trainer_rules,
 from repro.kernels import ops as kops
 from repro.kernels import replay_ops as rops
 from repro.kernels.ops import use_pallas
+from repro.launch.mesh import make_ac_mesh, make_debug_mesh
 from repro.replay import buffer as rb
 from repro.replay import prioritized as per
 
@@ -132,12 +133,33 @@ def test_priority_scatter_matches_oracle_incl_window():
                                              window_start=4)))
 
 
+@pytest.mark.parametrize("rows,window_start", [
+    (4096, 0),        # 4 blocks of (8, 128), lane-dense, in place
+    (3000, 1000),     # ragged: padded to whole blocks, windowed
+])
+def test_priority_scatter_multiblock(rows, window_start):
+    """Updates spread over several HBM blocks, plus same-row neighbours
+    (one 128-lane row, read-modify-written in turn) and out-of-window
+    indices."""
+    pri = jax.random.uniform(jax.random.PRNGKey(9), (rows,))
+    lo, hi = window_start, window_start + rows
+    idx = jnp.asarray([lo, lo + 1, lo + 127, lo + 128, lo + 1030,
+                       hi - 1, hi, lo - 1, lo + 2050], jnp.int32)
+    vals = jnp.arange(1.0, 10.0)
+    out = rops.priority_scatter(pri, idx, vals, window_start=window_start,
+                                block=1024)
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(rops.priority_scatter_ref(pri, idx, vals,
+                                             window_start=window_start)))
+
+
 # --------------------------------------------------------------------------- #
 # shard_map wrappers + dispatch
 # --------------------------------------------------------------------------- #
 
 def _ac_mesh():
-    return jax.make_mesh((1, 1), ("ac", "batch"), devices=jax.devices()[:1])
+    return make_ac_mesh(1, 1)
 
 
 def test_sharded_wrappers_match_oracles_on_trivial_mesh():
@@ -173,7 +195,6 @@ def test_sharded_wrappers_match_oracles_on_trivial_mesh():
 def test_sharded_wrappers_match_oracles_multidevice():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices (sharded CI job)")
-    from repro.launch.mesh import make_ac_mesh
     for placement in ("ac", "dp"):    # dp: rows over BOTH mesh axes
         rules = trainer_rules(make_ac_mesh(2, 4), placement)
         data = jax.random.normal(jax.random.PRNGKey(12), (64, 3))
@@ -200,8 +221,7 @@ def test_ring_mode_dispatch():
         with use_rules(trainer_rules(_ac_mesh(), "ac")):
             assert rb._ring_mode(16) == "shard"
             assert rb._ring_mode(16, 8) == "shard"
-        mesh_dm = jax.make_mesh((1, 1), ("data", "model"),
-                                devices=jax.devices()[:1])
+        mesh_dm = make_debug_mesh(1, 1)
         with use_rules(standard_rules(mesh_dm)):
             # a ("data","model") mesh still maps batch -> ("data",):
             # the ring shards over it like any batch axis
@@ -216,8 +236,7 @@ def test_ring_mode_dispatch():
 def test_ring_mode_indivisible_rows_fall_back():
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a batch axis of size 2")
-    mesh = jax.make_mesh((1, 2), ("ac", "batch"),
-                         devices=jax.devices()[:2])
+    mesh = make_ac_mesh(1, 2)
     with use_pallas(), use_rules(trainer_rules(mesh, "ac")):
         assert rb._ring_mode(15) == "jnp"        # cap % groups != 0
         assert rb._ring_mode(16, 7) == "jnp"     # bsz % groups != 0
@@ -343,8 +362,7 @@ def test_mesh_pallas_rejects_indivisible_batch():
     from repro.core import SpreezeConfig, SpreezeTrainer
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a batch axis of size 2")
-    mesh = jax.make_mesh((1, 2), ("ac", "batch"),
-                         devices=jax.devices()[:2])
+    mesh = make_ac_mesh(1, 2)
     with pytest.raises(ValueError, match="ring shards"):
         SpreezeTrainer(SpreezeConfig(
             env_name="pendulum", algo="sac", num_envs=2, batch_size=33,

@@ -15,6 +15,7 @@ import jax
 import pytest
 
 from repro.core import SpreezeConfig, SpreezeTrainer
+from repro.launch.mesh import make_ac_mesh, make_debug_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHECK = os.path.join(ROOT, "tests", "sharded_check.py")
@@ -57,8 +58,7 @@ def test_trivial_ac_mesh_runs_sharded_path():
     (in/out shardings, use_rules tracing, device_put placement) on any
     device count — math must match the meshless trainer exactly."""
     import numpy as np
-    mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
+    mesh = make_ac_mesh(1, 1)
     tr_m = SpreezeTrainer(_cfg(mesh=mesh, rounds_per_dispatch=2))
     tr_r = SpreezeTrainer(_cfg(rounds_per_dispatch=2))
     for tr in (tr_m, tr_r):
@@ -81,8 +81,7 @@ def test_mesh_rejects_indivisible_q_ensemble():
     error (the check reads the REAL ensemble size from the state)."""
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for an ac axis of size 2")
-    mesh = jax.make_mesh((2, 1), ("ac", "batch"),
-                         devices=jax.devices()[:2])
+    mesh = make_ac_mesh(2, 1)
     with pytest.raises(ValueError, match="ensemble"):
         SpreezeTrainer(_cfg(mesh=mesh, algo="ddpg"))
 
@@ -95,8 +94,7 @@ def test_mesh_with_ambient_pallas_switch_runs_shard_map_ring():
     import numpy as np
     from repro.kernels import ops as kops
     from repro.kernels import replay_ops as rops
-    mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
+    mesh = make_ac_mesh(1, 1)
     rops.reset_trace_counts()
     with kops.use_pallas(True):
         tr = SpreezeTrainer(_cfg(mesh=mesh, rounds_per_dispatch=2))
@@ -116,8 +114,7 @@ def test_trainer_pins_pallas_switch_against_ambient_drift():
     ambient switch on before the first (lazy) megastep trace."""
     from repro.kernels import ops as kops
     from repro.kernels import replay_ops as rops
-    mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
+    mesh = make_ac_mesh(1, 1)
     tr = SpreezeTrainer(_cfg(mesh=mesh, use_pallas=False))
     rops.reset_trace_counts()
     with kops.use_pallas(True):     # ambient on; trainer pinned off
@@ -133,8 +130,7 @@ def test_eager_add_trace_not_shared_across_mesh_contexts():
     """The eager ring-write jit cache must key on the active mesh rules:
     a mesh trainer tracing first must not bake its sharding constraints
     into a later meshless trainer's replay pushes (and vice versa)."""
-    mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
+    mesh = make_ac_mesh(1, 1)
     tr_m = SpreezeTrainer(_cfg(mesh=mesh))
     tr_m._warmup()                  # traces the eager add under rules
     tr_r = SpreezeTrainer(_cfg())   # same shapes, no mesh
@@ -147,15 +143,13 @@ def test_eager_add_trace_not_shared_across_mesh_contexts():
 
 
 def test_mesh_requires_ac_batch_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    mesh = make_debug_mesh(1, 1)
     with pytest.raises(ValueError, match="ac"):
         SpreezeTrainer(_cfg(mesh=mesh))
 
 
 def test_mesh_requires_fused_path():
-    mesh = jax.make_mesh((1, 1), ("ac", "batch"),
-                         devices=jax.devices()[:1])
+    mesh = make_ac_mesh(1, 1)
     with pytest.raises(ValueError):
         SpreezeTrainer(_cfg(mesh=mesh, sync_mode=True))
 
@@ -163,8 +157,7 @@ def test_mesh_requires_fused_path():
 def test_mesh_capacity_divisibility():
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a batch axis of size 2")
-    mesh = jax.make_mesh((1, 2), ("ac", "batch"),
-                         devices=jax.devices()[:2])
+    mesh = make_ac_mesh(1, 2)
     with pytest.raises(ValueError, match="divisible"):
         SpreezeTrainer(_cfg(mesh=mesh, replay_capacity=257))
 

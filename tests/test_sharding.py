@@ -19,10 +19,7 @@ from jax.sharding import AbstractMesh
 
 def FakeMesh(shape: dict):
     """Abstract (device-less) mesh for rule-resolution tests."""
-    try:
-        return AbstractMesh(tuple(shape.values()), tuple(shape.keys()))
-    except TypeError:   # older jax: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(shape.items()))
+    return AbstractMesh(tuple(shape.values()), tuple(shape.keys()))
 
 
 def _rules(pod=False):
