@@ -50,7 +50,7 @@ def run_check():
     # placement sanity: Q ensemble on ``ac``, ring rows on ``batch``
     q_spec = jax.tree.leaves(tr_sh.state.q)[0].sharding.spec
     assert q_spec[0] == "ac", q_spec
-    ring_spec = tr_sh.replay.data["obs"].sharding.spec
+    ring_spec = tr_sh.replay.rows.sharding.spec
     assert ring_spec[0] in ("batch", ("batch",)), ring_spec
 
     for tr in (tr_ref, tr_sh):

@@ -1,4 +1,4 @@
-"""Group-local PER selection: the fused ``per_topk`` kernel vs the dense
+"""Group-local PER selection: ``per_topk`` (score kernel + top-k) vs the dense
 oracle (partial fill, ring-wrap layouts, window edges, k > live rows),
 the two-phase candidate merge, cross-mode/cross-layout determinism of
 PER draws, and the compiled-megastep probes (trace counts + no

@@ -135,7 +135,7 @@ def test_eager_add_trace_not_shared_across_mesh_contexts():
     tr_m._warmup()                  # traces the eager add under rules
     tr_r = SpreezeTrainer(_cfg())   # same shapes, no mesh
     tr_r._warmup()
-    sh = tr_r.replay.data["obs"].sharding
+    sh = tr_r.replay.rows.sharding
     mesh_names = set(getattr(getattr(sh, "mesh", None), "axis_names", ()))
     assert mesh_names != {"ac", "batch"}, (
         "meshless trainer's replay got committed onto the mesh trainer's "
