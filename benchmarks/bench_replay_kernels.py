@@ -14,7 +14,8 @@ paths at realistic shapes, (2) pin the jnp-oracle XLA-CPU numbers the
 throughput tables build on, and (3) let future PRs diff the arms.
 
 Run: ``PYTHONPATH=src python -m benchmarks.bench_replay_kernels
-[--tiny]``; ``--tiny`` is the CI smoke preset.
+[--tiny | --chip]``; ``--tiny`` is the CI smoke preset, ``--chip`` runs
+the PER arms alone at the trainer's TPU sizes (give it ``--out``).
 """
 from __future__ import annotations
 
@@ -151,6 +152,53 @@ def bench_per_scores(cap, iters) -> dict:
     return rec, ok
 
 
+def bench_priority_scatter(cap, k, iters) -> dict:
+    """PER re-prioritisation: the HBM read-modify-write kernel with the
+    whole vector as one block (the default up to 2^20 rows) and in four
+    blocks, against XLA's scatter. Indices are distinct, as PER draws without replacement, so
+    every arm must match the oracle bit for bit. Nothing is donated: each
+    arm pays one copy of the vector."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(4))
+    pri = jax.random.uniform(k1, (cap,)) + 0.1
+    idx = jax.random.permutation(k2, cap)[:k].astype(jnp.int32)
+    val = jnp.arange(k, dtype=jnp.float32) + 2.0
+    want = rops.priority_scatter_ref(pri, idx, val)
+    arms = {
+        "one_block": jax.jit(functools.partial(rops.priority_scatter,
+                                               block=cap)),
+        "four_blocks": jax.jit(functools.partial(rops.priority_scatter,
+                                                 block=cap // 4)),
+        "jnp": jax.jit(rops.priority_scatter_ref),
+    }
+    rec, ok = {}, True
+    for name, fn in arms.items():
+        ok &= _check(f"priority_scatter/{name}", fn(pri, idx, val), want)
+        rec[f"{name}_ms"] = round(
+            time_call(lambda fn=fn: fn(pri, idx, val), iters) * 1e3, 3)
+    return rec, ok
+
+
+def main_chip(out: str) -> bool:
+    """The PER kernels at the trainer's chip sizes (2^20-row pool, batch
+    8192). The ring arms stay out: the row-loop baselines move
+    lane-unaligned rows, which Mosaic refuses."""
+    cap, k, iters = 1 << 20, 8192, 20
+    cfg = {"capacity": cap, "topk": k, "backend": jax.default_backend(),
+           "device_kind": jax.devices()[0].device_kind,
+           "interpret": jax.default_backend() != "tpu"}
+    per_rec, ok_p = bench_per_scores(cap, iters)
+    topk_rec, ok_t = bench_per_topk(cap, k, iters)
+    scat_rec, ok_s = bench_priority_scatter(cap, k, iters)
+    oracle_ok = bool(ok_p and ok_t and ok_s)
+    report = {"config": cfg, "per_scores": per_rec, "per_topk": topk_rec,
+              "priority_scatter": scat_rec, "oracle_ok": oracle_ok}
+    print(json.dumps(report))
+    with open(out, "w") as f:
+        json.dump(report, f, indent=2)
+        f.write("\n")
+    return oracle_ok
+
+
 def main(tiny: bool = False,
          out: str = os.path.join(ROOT, "BENCH_replay_kernels.json")) -> bool:
     if tiny:
@@ -165,15 +213,18 @@ def main(tiny: bool = False,
     gather_rec, ok_g = bench_ring_gather(cap, bsz, feat, iters)
     per_rec, ok_p = bench_per_scores(cap, iters)
     topk_rec, ok_t = bench_per_topk(cap, k, iters)
-    oracle_ok = bool(ok_w and ok_g and ok_p and ok_t)
+    scat_rec, ok_s = bench_priority_scatter(cap, k, iters)
+    oracle_ok = bool(ok_w and ok_g and ok_p and ok_t and ok_s)
     emit("replay_kernels", "ring_write", **write_rec)
     emit("replay_kernels", "ring_gather", **gather_rec)
     emit("replay_kernels", "per_scores", **per_rec)
     emit("replay_kernels", "per_topk", **topk_rec)
+    emit("replay_kernels", "priority_scatter", **scat_rec)
     emit("replay_kernels", "oracle", ok=oracle_ok)
     report = {"config": cfg, "ring_write": write_rec,
               "ring_gather": gather_rec, "per_scores": per_rec,
-              "per_topk": topk_rec, "oracle_ok": oracle_ok}
+              "per_topk": topk_rec, "priority_scatter": scat_rec,
+              "oracle_ok": oracle_ok}
     with open(out, "w") as f:
         json.dump(report, f, indent=2)
         f.write("\n")
@@ -184,7 +235,10 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke preset (small shapes, fewer iters)")
+    ap.add_argument("--chip", action="store_true",
+                    help="PER kernels only, at the trainer's TPU sizes")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "BENCH_replay_kernels.json"))
     args = ap.parse_args()
-    sys.exit(0 if main(tiny=args.tiny, out=args.out) else 1)
+    sys.exit(0 if (main_chip(args.out) if args.chip
+                   else main(tiny=args.tiny, out=args.out)) else 1)

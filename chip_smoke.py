@@ -15,12 +15,16 @@ Phases on one chip:
   a. SAC, uniform replay, ``pendulum``;
   b. SAC, PER (alpha 0.6, beta 0.4), 3-step returns, ``hopper``;
   c. one megastep with the Pallas ring and one with the jnp ring from
-     the same state and key: rings bitwise equal, parameters and losses
-     within 1e-5 relative.
+     the same state and key, for a's and b's configurations: rings (and
+     PER priority vectors) bitwise equal, parameters and losses within
+     1e-5 relative; then one PER draw with the kernels on and off from
+     the same pool and key picks the same indices, rows and weights and
+     re-prioritises them identically.
 With ``--chips 4``: SAC uniform (``pendulum``) and PER (``hopper``) on a
 2x2 mesh under placement ``ac`` (paper Fig. 2b) and ``dp`` (Fig. 2a),
 each against the single-device megastep on device 0 from the same state
-and key, to the tolerance of ``tests/sharded_check.py``.
+and key, held to what ``tests/sharded_check.py`` holds it to (see
+``mesh_phase``).
 
 Every phase requires ``tpu_custom_call`` (the Mosaic kernels) in the
 compiled megastep, finite losses, and no worker restart, hang,
@@ -153,14 +157,61 @@ def _assert_trees_close(what, ta, tb, rtol, atol=0.0):
         _assert_close(what, a, b, rtol, atol)
 
 
-def pallas_vs_jnp_phase(phase, sizes):
+def _per_draw(tr, replay, pallas, rules=None):
+    """One PER draw from ``replay`` and the re-prioritisation of the
+    drawn rows (synthetic TD errors), traced under the given kernel
+    switch and mesh rules -> (HLO text, (batch, idx, weights,
+    priorities))."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.distributed.sharding import use_rules
+    from repro.kernels import ops as kops
+    from repro.replay import prioritized
+    cfg, key = tr.cfg, jax.random.key(1)
+
+    def draw(r, k):
+        batch, idx, w = prioritized.sample(
+            r, k, cfg.batch_size, alpha=cfg.per_alpha, beta=cfg.per_beta)
+        td = jnp.arange(cfg.batch_size, dtype=jnp.float32) / 7.0 - 3.0
+        return (batch, idx, w,
+                prioritized.update_priorities(r, idx, td).priorities)
+
+    ctx = use_rules(rules) if rules is not None else contextlib.nullcontext()
+    with ctx, kops.use_pallas(pallas):
+        c = jax.jit(draw).lower(replay, key).compile()   # a fresh trace
+    return c.as_text(), c(replay, key)
+
+
+def _same_draws(what, got, want):
+    """PER draws from the same pool and key: identical indices, rows and
+    re-prioritised vectors; weights to ``sharded_check``'s 1e-6."""
+    import numpy as np
+    (b_got, i_got, w_got, p_got), (b_want, i_want, w_want, p_want) = (
+        got, want)
+    check(np.array_equal(np.asarray(i_got), np.asarray(i_want)),
+          f"{what}: PER drew different indices")
+    for k in b_want:
+        check(np.array_equal(np.asarray(b_got[k]), np.asarray(b_want[k])),
+              f"{what}: PER batch field {k!r} differs")
+    _assert_close(f"{what}: importance weights", w_got, w_want, 1e-6)
+    check(np.array_equal(np.asarray(p_got), np.asarray(p_want)),
+          f"{what}: re-prioritised vectors differ")
+    return int(np.unique(np.asarray(i_got)).size)
+
+
+def pallas_vs_jnp_phase(phase, sizes, **kw):
     """One megastep with the Pallas ring and one with the jnp ring, from
-    the same state and key."""
+    the same state and key. Under PER the priority vectors must match
+    bitwise too, and a PER draw from the resulting pool must pick the
+    same indices on both paths."""
     import jax
     import numpy as np
 
     from repro.core import SpreezeTrainer
-    cfg = dict(eval_every_rounds=0, **UNIFORM)
+    cfg = dict(eval_every_rounds=0, **kw)
     trs = [SpreezeTrainer(_config(sizes, **cfg)),
            SpreezeTrainer(_config(sizes, **{**cfg, "use_pallas": False}))]
     out = {}
@@ -171,22 +222,32 @@ def pallas_vs_jnp_phase(phase, sizes):
         _check_kernels(phase, text, kernels_on=on)
         out[f"compile_s_{'pallas' if on else 'jnp'}"] = compile_s
     pal, ref = trs
-    rings = [np.asarray(tr.replay.rows) for tr in trs]
-    check(np.array_equal(*rings), f"{phase}: warmup rings differ")
-    for a, b in zip(jax.tree.leaves((pal.state, pal.env_states, pal.key)),
-                    jax.tree.leaves((ref.state, ref.env_states, ref.key))):
+    for a, b in zip(jax.tree.leaves((pal.replay, pal.state, pal.env_states,
+                                     pal.key)),
+                    jax.tree.leaves((ref.replay, ref.state, ref.env_states,
+                                     ref.key)), strict=True):
         check(np.array_equal(np.asarray(a), np.asarray(b)),
-              f"{phase}: initial states differ")
+              f"{phase}: states after warmup differ")
     for tr in trs:
         t0 = time.perf_counter()
         _dispatch(tr)
         jax.block_until_ready(tr.state)
         out[f"run_s_{'pallas' if tr.use_pallas else 'jnp'}"] = (
             time.perf_counter() - t0)
-    check(np.array_equal(np.asarray(pal.replay.rows),
-                         np.asarray(ref.replay.rows)),
+    ring, ring_ref = (getattr(t.replay, "base", t.replay) for t in trs)
+    check(np.array_equal(np.asarray(ring.rows), np.asarray(ring_ref.rows)),
           f"{phase}: replay rings differ bitwise")
-    check(int(pal.replay.ptr) == int(ref.replay.ptr), f"{phase}: ring ptr")
+    check(int(ring.ptr) == int(ring_ref.ptr), f"{phase}: ring ptr")
+    if cfg.get("prioritized"):
+        check(np.array_equal(np.asarray(pal.replay.priorities),
+                             np.asarray(ref.replay.priorities)),
+              f"{phase}: priority vectors differ bitwise")
+        draws = []
+        for on in (True, False):
+            text, d = _per_draw(pal, pal.replay, on)
+            _check_kernels(f"{phase}: PER draw", text, kernels_on=on)
+            draws.append(d)
+        out["per_unique_draws"] = _same_draws(phase, *draws)
     _assert_trees_close(f"{phase}: params", pal.state, ref.state, 1e-5)
     _assert_close(f"{phase}: critic loss", pal.last_metrics["critic_loss"],
                   ref.last_metrics["critic_loss"], 1e-5)
@@ -195,50 +256,90 @@ def pallas_vs_jnp_phase(phase, sizes):
         peak_bytes_in_use=_peak_bytes(), **out)
 
 
+def _max_gap(ta, tb):
+    import jax
+    import numpy as np
+    return max(float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
+               for a, b in zip(jax.tree.leaves(ta), jax.tree.leaves(tb),
+                               strict=True))
+
+
 def mesh_phase(phase, sizes, mesh, **kw):
     """The sharded megastep under placements ``ac`` and ``dp`` against
-    the single-device megastep on device 0, from the same state and key
-    (tolerances of ``tests/sharded_check.py``)."""
+    the single-device megastep on device 0, from the same state and key,
+    held to what ``tests/sharded_check.py`` holds it to: ring bookkeeping
+    and PRNG key exact; with uniform replay, parameters and losses within
+    rtol 1e-3 / atol 1e-5; with PER, a draw and re-prioritisation from
+    the sharded pool identical to the single-device one on the same pool
+    (PER over a 2^20-row pool is too sensitive to compare parameters
+    after 32 updates: a last-bit difference in a TD error reorders
+    near-tied Gumbel scores, so the batches part ways; the gaps are
+    logged).
+
+    Both sides run with float32 matmuls at ``highest`` precision: at the
+    TPU's default (bfloat16 passes) a last-bit difference from the
+    sharded summation order can become a bfloat16 step, where the CPU
+    the tolerances were set on has exact float32 matmuls."""
     import jax
     import numpy as np
 
     from repro.core import SpreezeTrainer
-    ref = SpreezeTrainer(_config(sizes, eval_every_rounds=0, **kw))
-    ref._warmup()
-    compile_s, text = _compile(ref)
-    _check_kernels(phase, text)
-    _dispatch(ref)
-    for placement in ("ac", "dp"):
-        tr = SpreezeTrainer(_config(sizes, eval_every_rounds=0, mesh=mesh,
-                                    placement=placement, **kw))
-        tr._warmup()
-        sh_compile_s, text = _compile(tr)
-        _check_kernels(f"{phase}/{placement}", text)
-        t0 = time.perf_counter()
-        _dispatch(tr)
-        jax.block_until_ready(tr.state)
-        run_s = time.perf_counter() - t0
-        what = f"{phase}/{placement}"
-        ring, ring_ref = (getattr(t.replay, "base", t.replay)
-                          for t in (tr, ref))
-        check(int(ring.ptr) == int(ring_ref.ptr), f"{what}: ptr")
-        check(int(ring.size) == int(ring_ref.size), f"{what}: size")
-        check(np.array_equal(np.asarray(tr.key), np.asarray(ref.key)),
-              f"{what}: PRNG key")
-        _assert_trees_close(f"{what}: actor", ref.state.actor,
-                            tr.state.actor, 1e-3, 1e-5)
-        _assert_trees_close(f"{what}: q", ref.state.q, tr.state.q,
-                            1e-3, 1e-5)
-        _assert_close(f"{what}: critic loss",
-                      ref.last_metrics["critic_loss"],
-                      tr.last_metrics["critic_loss"], 1e-3, 1e-5)
-        log(what, label="smoke run, not a benchmark",
-            mesh=dict(mesh.shape), compile_s_single=compile_s,
-            compile_s_sharded=sh_compile_s, run_s_sharded=run_s,
-            critic_loss_sharded=_losses(tr), critic_loss_single=_losses(ref),
-            matches_single_device=True, peak_bytes_in_use=_peak_bytes())
-        del tr
-        gc.collect()
+    per = kw.get("prioritized", False)
+    with jax.default_matmul_precision("highest"):
+        ref = SpreezeTrainer(_config(sizes, eval_every_rounds=0, **kw))
+        ref._warmup()
+        compile_s, text = _compile(ref)
+        _check_kernels(phase, text)
+        _dispatch(ref)
+        for placement in ("ac", "dp"):
+            what = f"{phase}/{placement}"
+            tr = SpreezeTrainer(_config(sizes, eval_every_rounds=0,
+                                        mesh=mesh, placement=placement,
+                                        **kw))
+            tr._warmup()
+            sh_compile_s, text = _compile(tr)
+            _check_kernels(what, text)
+            t0 = time.perf_counter()
+            _dispatch(tr)
+            jax.block_until_ready(tr.state)
+            run_s = time.perf_counter() - t0
+            ring, ring_ref = (getattr(t.replay, "base", t.replay)
+                              for t in (tr, ref))
+            check(int(ring.ptr) == int(ring_ref.ptr), f"{what}: ptr")
+            check(int(ring.size) == int(ring_ref.size), f"{what}: size")
+            check(np.array_equal(np.asarray(tr.key), np.asarray(ref.key)),
+                  f"{what}: PRNG key")
+            _losses(tr)
+            out = dict(
+                param_gap=_max_gap((ref.state.actor, ref.state.q),
+                                   (tr.state.actor, tr.state.q)),
+                critic_loss_sharded=_losses(tr),
+                critic_loss_single=_losses(ref))
+            if per:
+                out["priority_gap"] = _max_gap(ref.replay.priorities,
+                                               tr.replay.priorities)
+                text, got = _per_draw(tr, tr.replay, True, tr._rules())
+                _check_kernels(f"{what}: PER draw", text)
+                single = jax.device_put(tr.replay, jax.devices()[0])
+                text, want = _per_draw(tr, single, True)
+                _check_kernels(f"{what}: PER draw", text)
+                out["per_unique_draws"] = _same_draws(what, got, want)
+                del single, got, want
+            else:
+                _assert_trees_close(f"{what}: actor", ref.state.actor,
+                                    tr.state.actor, 1e-3, 1e-5)
+                _assert_trees_close(f"{what}: q", ref.state.q, tr.state.q,
+                                    1e-3, 1e-5)
+                _assert_close(f"{what}: critic loss",
+                              ref.last_metrics["critic_loss"],
+                              tr.last_metrics["critic_loss"], 1e-3, 1e-5)
+            log(what, label="smoke run, not a benchmark",
+                mesh=dict(mesh.shape), compile_s_single=compile_s,
+                compile_s_sharded=sh_compile_s, run_s_sharded=run_s,
+                matches_single_device=True, peak_bytes_in_use=_peak_bytes(),
+                **out)
+            del tr
+            gc.collect()
 
 
 def run(chips, sizes=SIZES):
@@ -253,7 +354,9 @@ def run(chips, sizes=SIZES):
         gc.collect()
         train_phase("b-per-nstep3-hopper", sizes, **PER)
         gc.collect()
-        pallas_vs_jnp_phase("c-pallas-vs-jnp-ring", sizes)
+        pallas_vs_jnp_phase("c-pallas-vs-jnp-ring", sizes, **UNIFORM)
+        gc.collect()
+        pallas_vs_jnp_phase("c-pallas-vs-jnp-per-hopper", sizes, **PER)
     d = jax.devices()
     return {"ok": True, "device": {"platform": d[0].platform,
                                    "kind": d[0].device_kind,
@@ -265,6 +368,8 @@ def main():
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the 2x2 mesh path")
     args = ap.parse_args()
+    # the TPU compiler logs under /tmp unless told otherwise
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     devs = jax.devices()
     if devs[0].platform != "tpu":

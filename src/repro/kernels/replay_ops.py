@@ -510,13 +510,16 @@ def _priority_scatter_kernel(lo_ref, idx_ref, val_ref, pri_ref, out_ref,
 
 def priority_scatter(priorities: jax.Array, idx: jax.Array,
                      values: jax.Array, *, window_start=0,
-                     block: int = 256 * 1024,
+                     block: int = 1024 * 1024,
                      interpret: Optional[bool] = None) -> jax.Array:
     """``priorities[idx - window_start] = values`` for the in-window
     subset of the (arbitrary, PER-sampled) indices; out-of-window
     updates are dropped (they belong to another group's shard). The
     vector stays in HBM and is updated in place via aliasing when the
-    caller donates it (and its length is a multiple of the block)."""
+    caller donates it (and its length is a multiple of the block).
+
+    Every block loops over all k updates, so the default block (4 MiB
+    of VMEM) holds a whole 2^20-row window: one pass over the updates."""
     TRACE_COUNTS["priority_scatter"] += 1
     (rows_local,) = priorities.shape
     k = idx.shape[0]

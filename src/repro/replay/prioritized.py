@@ -17,9 +17,9 @@ a TRUE ``-inf`` score (a finite floor like ``log(1e-12)`` loses to
 Gumbel noise and silently feeds all-zero rows into the update — the
 original bug). Draws beyond the live-row count cycle through the live
 draws (sampling with replacement once the pool is exhausted), and
-importance weights normalize over the written rows only, so a
-partially-filled pool doesn't deflate the live probabilities with the
-phantom mass of empty capacity slots.
+importance weights, normalized by the batch max, depend only on the
+drawn rows' priorities (the pool-wide normalizer cancels), so the empty
+capacity slots of a partially-filled pool cannot bias them.
 
 Two-phase selection (group-local PER): index selection routes through
 ``_select`` in every mode — each batch group (one group when meshless)
@@ -138,17 +138,15 @@ def sample(state: PrioritizedState, key, batch_size: int, *,
     batch = unpack_rows(state.base.layout,
                         gather_rows(state.base.rows, idx))
 
-    # importance weights: w_i = (N * P(i))^-beta, normalized by max.
-    # P(i) normalizes over the WRITTEN rows only — the 1e-12-floored
-    # mass of empty capacity slots used to bias live-row weights
-    # whenever the pool wasn't full. The sampled rows' priorities ride
-    # along with the selection candidates: indexing the sharded
-    # (capacity,) vector directly would make GSPMD all-gather it.
-    p = jnp.where(live, jnp.maximum(state.priorities, 1e-12) ** alpha, 0.0)
-    z = jnp.maximum(jnp.sum(p), 1e-12)
+    # importance weights: w_i = (N * P(i))^-beta with P(i) = p_i / sum p
+    # over the written rows, normalized by the batch max. N and the sum
+    # cancel in the normalization, so w_i = (min_j p_j / p_i)^beta over
+    # the drawn rows: no capacity-sized reduction, whose summation
+    # order (and so its last bit) would depend on how XLA fuses it. The
+    # sampled rows' priorities ride along with the selection candidates:
+    # indexing the sharded (capacity,) vector would all-gather it.
     p_sel = jnp.maximum(pri_sel, 1e-12) ** alpha
-    w = (n_live.astype(jnp.float32) * (p_sel / z)) ** (-beta)
-    w = w / jnp.maximum(jnp.max(w), 1e-12)
+    w = (jnp.min(p_sel) / p_sel) ** beta
     return batch, idx, w
 
 

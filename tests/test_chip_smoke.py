@@ -37,7 +37,18 @@ def test_one_chip_phases_at_tiny_size(no_kernel_check, capsys):
     phases = [json.loads(line)["smoke"]
               for line in capsys.readouterr().out.splitlines()]
     assert phases == ["a-uniform-pendulum", "b-per-nstep3-hopper",
-                      "c-pallas-vs-jnp-ring"]
+                      "c-pallas-vs-jnp-ring", "c-pallas-vs-jnp-per-hopper"]
+
+
+def test_pallas_vs_jnp_catches_a_wrong_per_kernel(no_kernel_check,
+                                                  monkeypatch):
+    """A priority scatter that drops one update must fail phase c."""
+    from repro.kernels import ops as kops
+    scatter = kops.priority_scatter
+    monkeypatch.setattr(kops, "priority_scatter",
+                        lambda p, i, v, **kw: scatter(p, i[1:], v[1:], **kw))
+    with pytest.raises(chip_smoke.SmokeFailure, match="differ"):
+        chip_smoke.pallas_vs_jnp_phase("c", TINY, **chip_smoke.PER)
 
 
 def test_four_chip_mesh_phase_at_tiny_size(no_kernel_check):
@@ -46,6 +57,27 @@ def test_four_chip_mesh_phase_at_tiny_size(no_kernel_check):
     from repro.launch.mesh import make_ac_mesh
     chip_smoke.mesh_phase("mesh", TINY, make_ac_mesh(2, 2),
                           **chip_smoke.PER)
+
+
+def test_mesh_phase_catches_a_wrong_candidate_merge(no_kernel_check,
+                                                    monkeypatch):
+    """A cross-group PER merge that picks the wrong candidates must fail
+    the mesh phase's draw check."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices (forced host devices)")
+    import jax.numpy as jnp
+
+    from repro.kernels import replay_ops as rops
+    from repro.launch.mesh import make_ac_mesh
+
+    def merge(scores, idx, pri, k):
+        v, pos = jax.lax.top_k(scores, k)
+        pos = jnp.roll(pos, 1)
+        return v, jnp.take(idx, pos), jnp.take(pri, pos)
+    monkeypatch.setattr(rops, "merge_topk_candidates", merge)
+    with pytest.raises(chip_smoke.SmokeFailure, match="different indices"):
+        chip_smoke.mesh_phase("mesh", TINY, make_ac_mesh(2, 2),
+                              **chip_smoke.PER)
 
 
 def test_phase_fails_on_supervisor_fault(no_kernel_check):

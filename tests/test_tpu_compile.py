@@ -12,6 +12,8 @@ one process may load the TPU library), and the persistent compilation
 cache is off around the compiles — an entry compiled for a described
 chip cannot be read back on the CPU.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -29,6 +31,7 @@ ENV_DIMS = {"pendulum": (3, 1), "hopper": (8, 2)}
 def one_chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else under /tmp
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
