@@ -1,0 +1,102 @@
+"""The harness finds every piece of a cell by name, and a later PR adds a
+configuration, a mix and a metric by adding files and entries only."""
+import json
+import os
+import re
+import shutil
+
+import compare
+from catalog import Catalog
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+
+def test_every_cell_resolves():
+    cat = Catalog()
+    for cell in cat.spec["workloads"]:
+        got, cfg, mix, limits = cat.workload(cell["name"])
+        assert got is cell
+        assert cfg["source"] == next(c["source"] for c in cat.spec["configs"]
+                                     if c["name"] == cell["config"])
+        assert limits and set(limits) <= set(compare.NAMES)
+        assert mix["batch_size"] > 0
+    for group in ("end_to_end", "per_layer"):
+        for cell in cat.spec["workloads"]:
+            assert cat.metrics(group, cell)
+    for m in cat.spec["per_layer"]:
+        assert callable(cat.reader(m["name"]))
+
+
+def test_benchmark_json_shape():
+    spec = Catalog().spec
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert len(json.dumps(spec)) < 64 * 1024
+    names = [x["name"] for k in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for x in spec[k]]
+    assert len(names) == len(set(names))
+    assert all(NAME.match(n) for n in names)
+    keys = {"configs": {"name", "source", "file", "reduced", "why"},
+            "workloads": {"name", "config", "traffic", "chips", "why"},
+            "end_to_end": {"name", "unit", "better", "bound", "source"},
+            "per_layer": {"name", "unit", "better", "source", "layer",
+                          "moves"}}
+    for group, want in keys.items():
+        for x in spec[group]:
+            assert set(x) - {"workloads"} == want, x
+    for w in spec["workloads"]:
+        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+    assert {m["name"] for m in spec["end_to_end"]} >= {"setup_s"}
+    e2e = {m["name"] for m in spec["end_to_end"]}
+    assert all(m["moves"] in e2e for m in spec["per_layer"])
+
+
+def test_dummy_addition_is_files_only(tmp_path):
+    """Copy the benchmark, add a configuration, a mix, a limits file and
+    a metric reader plus their entries, and see the harness list and
+    find them with no edit to any file that was there."""
+    here = Catalog()
+    shutil.copy(os.path.join(here.root, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(here.bench, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    b = tmp_path / "bench"
+    cfg = json.loads((b / "configs" / "sac_pendulum.json").read_text())
+    (b / "configs" / "dummy_cfg.json").write_text(json.dumps(
+        dict(cfg, env="dummy_env")))
+    mix = json.loads((b / "traffic" / "uniform_utd24.json").read_text())
+    (b / "traffic" / "dummy_mix.json").write_text(json.dumps(
+        dict(mix, updates_per_round=1)))
+    (b / "limits" / "dummy_cfg.dummy_mix.json").write_text(json.dumps(
+        {"loss_gap": 1, "grad_gap": 1, "change_gap": 1}))
+    (b / "metrics" / "dummy_metric.py").write_text(
+        "def read(run):\n    return run['frames'] * 2.0\n")
+    spec = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "dummy_cfg", "source": "x",
+                            "file": "bench/configs/dummy_cfg.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "dummy_cfg.dummy_mix",
+                              "config": "dummy_cfg", "traffic": "dummy_mix",
+                              "chips": 1, "why": "test"})
+    spec["per_layer"].append({"name": "dummy_metric", "unit": "%",
+                              "better": "higher", "source": "device_trace",
+                              "layer": "test", "moves": "env_frames_per_s",
+                              "workloads": ["dummy_cfg.dummy_mix"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    cat = Catalog(str(tmp_path))
+    assert "dummy_cfg" in cat.names("configs")
+    assert "dummy_mix" in cat.names("traffic")
+    assert "dummy_metric" in cat.names("metrics")
+    cell, cfg2, mix2, limits = cat.workload("dummy_cfg.dummy_mix")
+    assert cfg2["env"] == "dummy_env" and mix2["updates_per_round"] == 1
+    assert "dummy_metric" in [m["name"]
+                              for m in cat.metrics("per_layer", cell)]
+    assert cat.reader("dummy_metric")({"frames": 3}) == 6.0
+    # the dummy metric is reported in its cell only
+    other = cat.workload("sac_hopper.per_n3_utd2")[0]
+    assert "dummy_metric" not in [m["name"]
+                                  for m in cat.metrics("per_layer", other)]
+    after = {p: p.read_bytes() for p in before}
+    assert after == before
