@@ -79,6 +79,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.hlolint.contract import (CollectiveContract,
@@ -125,6 +126,13 @@ MEGASTEP_COLLECTIVE_CONTRACT = CollectiveContract(
 PER_MEGASTEP_COLLECTIVE_CONTRACT = CollectiveContract(
     allow=MEGASTEP_COLLECTIVE_CONTRACT.allow + kops.PER_TOPK_COLLECTIVES,
     max_elems="capacity")
+
+#: names one stage of the megastep (``sampler_chunk``, ``ring_write``,
+#: ``sample``, ``update``, ``reprioritise``) in its ops' ``op_name``
+#: metadata, which a device trace carries; the ops themselves do not
+#: change. Host spans of the train loop and the workers are
+#: ``jax.profiler`` annotations named ``spreeze.*``.
+_scope = jax.named_scope
 
 HLOLINT_CONTRACTS = (
     # single-device fused megasteps: donation must fully alias (the
@@ -440,13 +448,16 @@ class SpreezeTrainer:
                 def one(carry, _):
                     state, replay, key = carry
                     key, k1, k2 = jax.random.split(key, 3)
-                    batch, idx, w = per.sample(
-                        replay, k1, cfg.batch_size,
-                        alpha=cfg.per_alpha, beta=cfg.per_beta)
-                    batch["weight"] = w
-                    state, metrics = update(state, batch, k2)
-                    replay = per.update_priorities(replay, idx,
-                                                   metrics["td_abs"])
+                    with _scope("sample"):
+                        batch, idx, w = per.sample(
+                            replay, k1, cfg.batch_size,
+                            alpha=cfg.per_alpha, beta=cfg.per_beta)
+                        batch["weight"] = w
+                    with _scope("update"):
+                        state, metrics = update(state, batch, k2)
+                    with _scope("reprioritise"):
+                        replay = per.update_priorities(replay, idx,
+                                                       metrics["td_abs"])
                     return (state, replay, key), metrics["critic_loss"]
 
                 (state, replay, key), closs = jax.lax.scan(
@@ -459,8 +470,10 @@ class SpreezeTrainer:
                 def one(carry, _):
                     state, key = carry
                     key, k1, k2 = jax.random.split(key, 3)
-                    batch = rb.sample(replay, k1, cfg.batch_size)
-                    state, metrics = update(state, batch, k2)
+                    with _scope("sample"):
+                        batch = rb.sample(replay, k1, cfg.batch_size)
+                    with _scope("update"):
+                        state, metrics = update(state, batch, k2)
                     return (state, key), metrics["critic_loss"]
 
                 (state, key), closs = jax.lax.scan(
@@ -533,9 +546,11 @@ class SpreezeTrainer:
             def megastep(state, replay, env_states, key):
                 def one_round(carry, _):
                     state, replay, env_states, key = carry
-                    env_states, flat, key, mrew = sampler_chunk(
-                        state.actor, env_states, key)
-                    replay = push(replay, flat)
+                    with _scope("sampler_chunk"):
+                        env_states, flat, key, mrew = sampler_chunk(
+                            state.actor, env_states, key)
+                    with _scope("ring_write"):
+                        replay = push(replay, flat)
                     state, replay, key, closs = update_round(
                         state, replay, key)
                     return (state, replay, env_states, key), (mrew, closs)
@@ -864,7 +879,8 @@ class SpreezeTrainer:
                     solved_at = runtime.solved_time
                     break
                 # --- finite guard: poll settled flags, roll back on NaN
-                bad_round = self._poll_guard()
+                with TraceAnnotation("spreeze.guard_poll"):
+                    bad_round = self._poll_guard()
                 if bad_round is not None:
                     round_i = self._rollback(runtime, hist, bad_round)
                     continue
@@ -886,38 +902,44 @@ class SpreezeTrainer:
                 if clock is not None and clock.nan(round_i):
                     self.state = self.state._replace(
                         actor=faults.poison_actor(self.state.actor))
-                if self.use_fused:
-                    # --- one device-resident megastep = R whole rounds ----
-                    with self._sanitize_scope():
-                        (self.state, self.replay, self.env_states, self.key,
-                         self.last_metrics) = self._megastep(
-                            self.state, self.replay, self.env_states,
-                            self.key)
-                    self.total_frames += frames_per_chunk * window
-                    self.total_updates += cfg.updates_per_round * window
-                    # enqueue the dispatch's finite flag; polled next
-                    # iteration once the buffer settles (never syncs)
-                    self._guard_q.append(
-                        (round_i, self.last_metrics["carry_finite"]))
-                else:
-                    # --- sampler "process": dispatch, don't block ---------
-                    with self._sanitize_scope():
-                        self.env_states, exp, self.key, _ = self._sampler(
-                            self.state.actor, self.env_states, self.key)
-                        self.replay = self.transfer.push(self.replay, exp)
-                    self.total_frames += frames_per_chunk
-                    if cfg.sync_mode:
-                        jax.block_until_ready(exp)  # Fig. 4a: handoff wait  # tracelint: allow[host-transfer] -- sync_mode ablation measures exactly this stall
-                    # --- updater "process" --------------------------------
-                    with self._sanitize_scope():
-                        self.replay = self.transfer.flush(self.replay)
-                        self.state, self.replay, self.key, closs = \
-                            self._update_round(self.state, self.replay,
-                                               self.key)
-                    self.total_updates += cfg.updates_per_round
-                    if cfg.sync_mode:
-                        # tracelint: allow[host-transfer] -- sync_mode ablation measures exactly this stall
-                        jax.block_until_ready(closs)
+                # one span per dispatch, numbered by its first round
+                with StepTraceAnnotation("spreeze.dispatch",
+                                         step_num=round_i):
+                    if self.use_fused:
+                        # --- one device-resident megastep = R rounds -----
+                        with self._sanitize_scope():
+                            (self.state, self.replay, self.env_states,
+                             self.key, self.last_metrics) = self._megastep(
+                                self.state, self.replay, self.env_states,
+                                self.key)
+                        self.total_frames += frames_per_chunk * window
+                        self.total_updates += (cfg.updates_per_round
+                                               * window)
+                        # enqueue the dispatch's finite flag; polled next
+                        # iteration once the buffer settles (never syncs)
+                        self._guard_q.append(
+                            (round_i, self.last_metrics["carry_finite"]))
+                    else:
+                        # --- sampler "process": dispatch, don't block -----
+                        with self._sanitize_scope():
+                            self.env_states, exp, self.key, _ = \
+                                self._sampler(self.state.actor,
+                                              self.env_states, self.key)
+                            self.replay = self.transfer.push(self.replay,
+                                                             exp)
+                        self.total_frames += frames_per_chunk
+                        if cfg.sync_mode:
+                            jax.block_until_ready(exp)  # Fig. 4a: handoff wait  # tracelint: allow[host-transfer] -- sync_mode ablation measures exactly this stall
+                        # --- updater "process" ----------------------------
+                        with self._sanitize_scope():
+                            self.replay = self.transfer.flush(self.replay)
+                            self.state, self.replay, self.key, closs = \
+                                self._update_round(self.state, self.replay,
+                                                   self.key)
+                        self.total_updates += cfg.updates_per_round
+                        if cfg.sync_mode:
+                            # tracelint: allow[host-transfer] -- sync_mode ablation measures exactly this stall
+                            jax.block_until_ready(closs)
                 # --- eval / viz "processes" -------------------------------
                 want_viz = _window_hits(round_i, window,
                                         cfg.viz_every_rounds)
@@ -925,78 +947,86 @@ class SpreezeTrainer:
                                          cfg.eval_every_rounds)
                 if want_viz or want_eval:
                     tb = time.perf_counter()
-                    if runtime is not None:
-                        # async: publish the snapshot, keep dispatching —
-                        # the workers consume it on their own streams
-                        # (eval_key carries the round index; the workers
-                        # fold the PRNG streams off-thread)
-                        runtime.publish(rt.Snapshot(
-                            round_i=round_i, actor=self._snapshot_actor(),
-                            eval_key=round_i, viz_key=round_i,
-                            t=tb - t0, frames=self.total_frames,
-                            steps=self.total_updates, want_eval=want_eval,
-                            want_viz=want_viz))
-                    else:
-                        # inline (sync ablation): block the train thread
-                        if want_viz:
-                            self._viz_dump(
-                                self._actor_for_eval(round_i),
-                                jax.random.fold_in(self._viz_key, round_i),
-                                round_i)
-                        if want_eval:
-                            # tracelint: allow[host-transfer] -- inline-eval ablation: blocking the train thread is the measured condition
-                            ret = float(self._eval(
-                                self._actor_for_eval(round_i),
-                                jax.random.fold_in(self._eval_key,
-                                                   round_i)))
-                            t = time.perf_counter() - t0
-                            hist.record_eval(t, ret, self.total_frames,
-                                             self.total_updates,
-                                             round_i=round_i)
-                            if log_cb:
-                                log_cb(t, ret, self.total_frames,
-                                       self.total_updates)
-                            if (target_return is not None
-                                    and ret >= target_return
-                                    and solved_at is None):
-                                solved_at = t
-                                hist.eval_blocked_s += (
-                                    time.perf_counter() - tb)
-                                break
+                    with TraceAnnotation("spreeze.publish"):
+                        if runtime is not None:
+                            # async: publish the snapshot, keep
+                            # dispatching — the workers consume it on their
+                            # own streams (eval_key carries the round index;
+                            # the workers fold the PRNG streams off-thread)
+                            runtime.publish(rt.Snapshot(
+                                round_i=round_i,
+                                actor=self._snapshot_actor(),
+                                eval_key=round_i, viz_key=round_i,
+                                t=tb - t0, frames=self.total_frames,
+                                steps=self.total_updates,
+                                want_eval=want_eval, want_viz=want_viz))
+                        else:
+                            # inline (sync ablation): block the train thread
+                            if want_viz:
+                                self._viz_dump(
+                                    self._actor_for_eval(round_i),
+                                    jax.random.fold_in(self._viz_key,
+                                                       round_i),
+                                    round_i)
+                            if want_eval:
+                                # tracelint: allow[host-transfer] -- inline-eval ablation: blocking the train thread is the measured condition
+                                ret = float(self._eval(
+                                    self._actor_for_eval(round_i),
+                                    jax.random.fold_in(self._eval_key,
+                                                       round_i)))
+                                t = time.perf_counter() - t0
+                                hist.record_eval(t, ret,
+                                                 self.total_frames,
+                                                 self.total_updates,
+                                                 round_i=round_i)
+                                if log_cb:
+                                    log_cb(t, ret, self.total_frames,
+                                           self.total_updates)
+                                if (target_return is not None
+                                        and ret >= target_return
+                                        and solved_at is None):
+                                    solved_at = t
+                                    hist.eval_blocked_s += (
+                                        time.perf_counter() - tb)
+                                    break
                     hist.eval_blocked_s += time.perf_counter() - tb
                 # --- periodic full-state snapshot (preemption safety) -----
                 if (want_snaps and _window_hits(round_i, window,
                                                 cfg.snapshot_every_rounds)
                         and (time.perf_counter() - last_snap_t
                              >= cfg.snapshot_min_interval_s)):
-                    # meta records the NEXT round: everything through
-                    # round_i+window-1 is in the bundle, so a resumed
-                    # run picks up exactly where this one left off
-                    if runtime is not None:
-                        # only copy when the writer will pick it up: a
-                        # bundle replaced latest-wins still costs a
-                        # device dispatch to build
-                        if runtime.state_slot_free():
-                            runtime.publish_state(resume_lib.publishable(
-                                self, hist, round_i + window))
+                    with TraceAnnotation("spreeze.snapshot"):
+                        # meta records the NEXT round: everything through
+                        # round_i+window-1 is in the bundle, so a resumed
+                        # run picks up exactly where this one left off
+                        if runtime is not None:
+                            # only copy when the writer will pick it up: a
+                            # bundle replaced latest-wins still costs a
+                            # device dispatch to build
+                            if runtime.state_slot_free():
+                                runtime.publish_state(
+                                    resume_lib.publishable(
+                                        self, hist, round_i + window))
+                                last_snap_t = time.perf_counter()
+                        else:
+                            # inline path syncs anyway; vet the bundle so
+                            # a poisoned state never becomes a rollback
+                            # target
+                            resume_lib.write_bundle(
+                                cfg.snapshot_dir,
+                                resume_lib.publishable(self, hist,
+                                                       round_i + window),
+                                keep=cfg.keep_snapshots,
+                                require_finite=True)
                             last_snap_t = time.perf_counter()
-                    else:
-                        # inline path syncs anyway; vet the bundle so a
-                        # poisoned state never becomes a rollback target
-                        resume_lib.write_bundle(
-                            cfg.snapshot_dir,
-                            resume_lib.publishable(self, hist,
-                                                   round_i + window),
-                            keep=cfg.keep_snapshots,
-                            require_finite=True)
-                        last_snap_t = time.perf_counter()
                 round_i += window
 
-            # tracelint: allow[host-transfer] -- end-of-run barrier closing the timed window
-            jax.block_until_ready(self.state.step)
-            # drain the guard queue: a run whose final dispatches went
-            # non-finite must fail loudly, never return as a success
-            bad_round = self._poll_guard(blocking=True)
+            with TraceAnnotation("spreeze.barrier"):
+                # tracelint: allow[host-transfer] -- end-of-run barrier closing the timed window
+                jax.block_until_ready(self.state.step)
+                # drain the guard queue: a run whose final dispatches went
+                # non-finite must fail loudly, never return as a success
+                bad_round = self._poll_guard(blocking=True)
             if bad_round is not None:
                 raise faults.FiniteGuardError(
                     f"megastep carry went non-finite at round {bad_round} "

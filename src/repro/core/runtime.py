@@ -58,8 +58,11 @@ silently leaking the thread.
 The runtime is deliberately JAX-free: ``eval_fn(actor, key) -> float``
 and ``viz_fn(actor, key, round_i)`` are opaque callables, so the same
 machinery drives compiled device functions and plain-Python test
-doubles. Worker exceptions are captured and re-raised in the train
-thread from ``drain()`` / ``close()``.
+doubles. Its one use of JAX is the profiler's host spans around each
+handler's work (``spreeze.eval``, ``spreeze.viz``,
+``spreeze.state_write``), which cost a check when no trace is on.
+Worker exceptions are captured and re-raised in the train thread from
+``drain()`` / ``close()``.
 """
 from __future__ import annotations
 
@@ -69,6 +72,8 @@ import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Set
+
+from jax.profiler import TraceAnnotation
 
 #: error classes the supervisor treats as transient I/O trouble worth
 #: retrying (ConnectionError/TimeoutError are OSError subclasses —
@@ -520,15 +525,19 @@ class HostRuntime:
             self._route_locked(snap)
 
     def _handle_state(self, item: Any) -> None:
-        self._state_fn(item)
+        with TraceAnnotation("spreeze.state_write"):
+            self._state_fn(item)
         with self._cond:
             if self._claim_abandoned_locked():
                 return
             self.state_done += 1
 
     def _handle_eval(self, snap: Snapshot) -> None:
-        # tracelint: allow[host-transfer] -- worker-thread conversion: the whole point of the async runtime is that this sync happens OFF the train loop's dispatch thread
-        ret = float(self._eval_fn(snap.actor, snap.eval_key))
+        # the span holds the wait for the result: a trace tells a worker
+        # blocked on the device from the train thread
+        with TraceAnnotation("spreeze.eval"):
+            # tracelint: allow[host-transfer] -- worker-thread conversion: the whole point of the async runtime is that this sync happens OFF the train loop's dispatch thread
+            ret = float(self._eval_fn(snap.actor, snap.eval_key))
         with self._cond:
             if self._claim_abandoned_locked():
                 return
@@ -544,7 +553,8 @@ class HostRuntime:
                 self.solved.set()
 
     def _handle_viz(self, snap: Snapshot) -> None:
-        self._viz_fn(snap.actor, snap.viz_key, snap.round_i)
+        with TraceAnnotation("spreeze.viz"):
+            self._viz_fn(snap.actor, snap.viz_key, snap.round_i)
         with self._cond:
             if self._claim_abandoned_locked():
                 return

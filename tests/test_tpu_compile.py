@@ -12,11 +12,13 @@ one process may load the TPU library), and the persistent compilation
 cache is off around the compiles — an entry compiled for a described
 chip cannot be read back on the CPU.
 """
+import contextlib
 import os
 
 import jax
 import jax.numpy as jnp
 import pytest
+from _hlo_program import program
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import _compat
@@ -120,3 +122,35 @@ def test_megastep_compiles_with_mosaic_kernels(one_chip, monkeypatch):
     assert c.as_text().count("tpu_custom_call") >= 2  # ring write + gather
     assert (c.memory_analysis().alias_size_in_bytes
             >= tr.replay.rows.size * 4)                # ring donated
+
+
+@pytest.mark.parametrize("prioritized", [False, True])
+def test_megastep_stage_scopes_leave_the_v5e_program_unchanged(
+        one_chip, monkeypatch, prioritized):
+    """The megastep's stage scopes (``pipeline._scope``) name its ops in
+    the metadata a device trace reads, and change no op of the program
+    the chip's compiler makes."""
+    from repro.core import SpreezeConfig, SpreezeTrainer, pipeline
+    monkeypatch.setattr(_compat, "interpret_default", lambda: False)
+
+    def optimized():
+        jax.clear_caches()
+        tr = SpreezeTrainer(SpreezeConfig(
+            env_name="hopper" if prioritized else "pendulum",
+            num_envs=64, chunk_len=8, batch_size=512, replay_capacity=8192,
+            updates_per_round=2, rounds_per_dispatch=2,
+            prioritized=prioritized, nstep=3 if prioritized else 1,
+            use_pallas=True))
+        args = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                            (tr.state, tr.replay, tr.env_states, tr.key))
+        return tr._megastep.lower(*args).compile().as_text()
+
+    scoped = optimized()
+    monkeypatch.setattr(pipeline, "_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = optimized()
+    jax.clear_caches()
+    assert "/update/" in scoped and "/update/" not in plain
+    ops = program(scoped)
+    assert "tpu_custom_call" in ops and ", metadata=" not in ops
+    assert ops == program(plain)
