@@ -81,7 +81,7 @@ def bench_ring_gather(cap, bsz, feat, iters) -> dict:
 
 
 def bench_per_topk(cap, k, iters) -> dict:
-    """Score kernel + ``lax.top_k`` vs the dense jnp oracle. Scores must
+    """Score pass + threshold top-k vs the dense jnp oracle. Scores must
     match the oracle bit-for-bit; indices match on
     every finite-score slot (-inf slots carry ``IDX_SENTINEL`` in the
     kernel — unspecified and unused, see ``replay.prioritized``). Also

@@ -8,7 +8,7 @@ in one process and checks what comes out. The numbers it prints
 a few dispatches and are labelled ``smoke``: they show the path runs,
 they are not measurements.
 
-    python3 chip_smoke.py             # one chip: phases a, b, c
+    python3 chip_smoke.py             # one chip: phases a, b, c, d
     python3 chip_smoke.py --chips 4   # 2x2 ("ac","batch") mesh path only
 
 Phases on one chip:
@@ -19,7 +19,13 @@ Phases on one chip:
      PER priority vectors) bitwise equal, parameters and losses within
      1e-5 relative; then one PER draw with the kernels on and off from
      the same pool and key picks the same indices, rows and weights and
-     re-prioritises them identically.
+     re-prioritises them identically;
+  d. ``per_topk`` (score pass, threshold top-k) against the dense
+     ``per_topk_ref`` over a window of ``replay_capacity`` rows with k =
+     ``batch_size``, on continuous scores, coarse ties straddling the
+     k-th place, +0.0 / -0.0 scores with the k-th among either, and a
+     partly filled window: scores and priorities bit for bit, indices
+     on every finite-score slot.
 With ``--chips 4``: SAC uniform (``pendulum``) and PER (``hopper``) on a
 2x2 mesh under placement ``ac`` (paper Fig. 2b) and ``dp`` (Fig. 2a),
 each against the single-device megastep on device 0 from the same state
@@ -256,6 +262,61 @@ def pallas_vs_jnp_phase(phase, sizes, **kw):
         peak_bytes_in_use=_peak_bytes(), **out)
 
 
+def _topk_window(kind, rows, seed=0):
+    """(priorities, gumbel, alpha) whose scores are of the given kind
+    (see ``per_topk_phase``)."""
+    import jax
+    import jax.numpy as jnp
+    k1, k2 = jax.random.split(jax.random.key(seed))
+    slot = jnp.arange(rows)
+    pri = jax.random.uniform(k1, (rows,)) + 0.01
+    g = jax.random.gumbel(k2, (rows,))
+    if kind == "ties":                  # a grid of 1/4: long runs of ties
+        return jnp.where(slot % 7 == 0, 2.0, 1.0), jnp.round(g * 4) / 4, 0.6
+    if kind.startswith("signed_zero"):  # alpha 0: -0.0, +0.0 and 1.0
+        pos = 10 if kind.endswith("pos") else 6     # +0.0s per 1000 rows
+        return (jnp.full((rows,), 0.5),
+                jnp.where(slot % 1000 == 0, 1.0,
+                          jnp.where(slot % 1000 <= pos, 0.0, -0.0)), 0.0)
+    if kind == "partial":               # fewer live rows than k
+        return jnp.where(slot < 5000, pri, 0.0), g, 0.6
+    return pri, g, 0.6
+
+
+def per_topk_phase(phase, sizes):
+    """``per_topk`` against ``per_topk_ref`` at the PER window's size."""
+    import jax
+    import numpy as np
+
+    from repro.kernels import replay_ops as rops
+    rows, k = sizes["replay_capacity"], sizes["batch_size"]
+    out = {}
+    for kind in ("continuous", "ties", "signed_zero_neg", "signed_zero_pos",
+                 "partial"):
+        pri, g, alpha = _topk_window(kind, rows)
+        got, want = (
+            [np.asarray(x) for x in jax.jit(
+                lambda p, n: fn(p, n, alpha, k))(pri, g)]
+            for fn in (rops.per_topk, rops.per_topk_ref))
+        what = f"{phase}/{kind}"
+        check(np.array_equal(got[0].view(np.int32), want[0].view(np.int32)),
+              f"{what}: scores differ")
+        fin = np.isfinite(want[0])
+        check(np.array_equal(got[1][fin], want[1][fin]),
+              f"{what}: indices differ")
+        check((got[1][~fin] == rops.IDX_SENTINEL).all(),
+              f"{what}: -inf slots without the sentinel")
+        check(np.array_equal(got[2][fin], want[2][fin]),
+              f"{what}: priorities differ")
+        zero = want[0] == 0
+        out[kind] = dict(finite=int(fin.sum()),
+                         neg_zero=int((zero & np.signbit(want[0])).sum()),
+                         pos_zero=int((zero & ~np.signbit(want[0])).sum()),
+                         kth=float(want[0][-1]))
+    log(phase, label="smoke run, not a benchmark", rows=rows, k=k,
+        bit_equal=True, **out)
+
+
 def _max_gap(ta, tb):
     import jax
     import numpy as np
@@ -357,6 +418,8 @@ def run(chips, sizes=SIZES):
         pallas_vs_jnp_phase("c-pallas-vs-jnp-ring", sizes, **UNIFORM)
         gc.collect()
         pallas_vs_jnp_phase("c-pallas-vs-jnp-per-hopper", sizes, **PER)
+        gc.collect()
+        per_topk_phase("d-per-topk-vs-dense", sizes)
     d = jax.devices()
     return {"ok": True, "device": {"platform": d[0].platform,
                                    "kind": d[0].device_kind,

@@ -128,8 +128,8 @@ def priority_scatter(priorities, idx, values) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("alpha", "k"))
 def per_topk(priorities, gumbel, alpha: float, k: int):
-    """PER score kernel + top-k selection. -> (scores (k,), global
-    indices (k,), priorities at the indices (k,))."""
+    """PER score pass + threshold top-k selection. -> (scores (k,),
+    global indices (k,), priorities at the indices (k,))."""
     return _replay.per_topk(priorities, gumbel, alpha, k)
 
 
@@ -233,8 +233,8 @@ def per_scores_sharded(priorities, gumbel, alpha: float,
 def per_topk_sharded(priorities, gumbel, alpha: float, k: int,
                      rules: MeshRules):
     """Mesh-native two-phase PER selection (the ROADMAP's RDMA-local
-    sampling): each batch group runs ``per_topk`` (score kernel + top-k)
-    on its local priority shard (window offset = its first global ring
+    sampling): each batch group runs ``per_topk`` (scores + threshold
+    top-k) on its local priority shard (window offset = its first global ring
     slot) and emits k candidates ``(score, global_idx, priority)``; an
     ``all_gather`` of the ``(groups * k,)`` candidates over the batch
     axes — the ONLY cross-group traffic, never capacity-proportional —

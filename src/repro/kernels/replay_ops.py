@@ -22,10 +22,13 @@ rows into blocks and pipeline the HBM<->VMEM traffic with
 * ``per_scores`` — blocked elementwise pass over lane-dense
   ``(rows, 128)`` tiles producing the Gumbel-top-k sampling scores for
   the PER pool (empty slots masked to a true -inf).
-* ``per_topk`` — PER selection: the ``per_scores`` kernel, then XLA's
-  ``top_k`` over the window's scores (Pallas TPU has no ``top_k``
-  lowering). Under ``shard_map`` each batch group emits its local k
-  candidates ``(score, global_idx, priority)`` and
+* ``per_topk`` — PER selection: the ``per_scores`` pass writing
+  order-preserving int32 keys, a kernel that finds the k-th largest key
+  bit by bit with the keys resident in VMEM, then XLA compacts the k
+  rows at or above it and ``top_k`` sorts only those k: nothing sorts
+  the window (steps above ``_order_keys``). Under ``shard_map`` each
+  batch group emits its local k candidates ``(score, global_idx,
+  priority)`` and
   ``merge_topk_candidates`` reduces the ``(groups * k,)`` gathered
   candidates — selection is group-local and the only cross-group PER
   traffic is k candidates per group, never anything proportional to
@@ -67,6 +70,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -364,8 +368,10 @@ def per_scores_ref(priorities: jax.Array, gumbel: jax.Array,
     return logp + gumbel
 
 
-def _per_scores_kernel(pri_ref, g_ref, out_ref, *, alpha: float):
-    out_ref[...] = per_scores_ref(pri_ref[...], g_ref[...], alpha)
+def _per_scores_kernel(pri_ref, g_ref, out_ref, *, alpha: float,
+                       keys: bool):
+    s = per_scores_ref(pri_ref[...], g_ref[...], alpha)
+    out_ref[...] = _order_keys(s) if keys else s
 
 
 def _lane_dense(x: jax.Array, rows_per_block: int) -> jax.Array:
@@ -383,6 +389,26 @@ def _block_rows(n: int, block: int) -> int:
     return r if r * LANES <= block else max(8, block // LANES // 8 * 8)
 
 
+def _score_pass(priorities, gumbel, alpha, block, interpret, *,
+                keys=False):
+    """The blocked score pass on lane-dense (block_rows, 128) tiles ->
+    (R, LANES) float32 scores, or their int32 order keys (``keys``);
+    padding slots score -inf."""
+    (rows,) = priorities.shape
+    br = _block_rows(rows, block)
+    p2, g2 = _lane_dense(priorities, br), _lane_dense(gumbel, br)
+    spec = pl.BlockSpec((br, LANES), lambda b: (b, 0))
+    return pl.pallas_call(
+        functools.partial(_per_scores_kernel, alpha=alpha, keys=keys),
+        grid=(p2.shape[0] // br,),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(
+            p2.shape, jnp.int32 if keys else jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(p2, g2)
+
+
 def per_scores(priorities: jax.Array, gumbel: jax.Array, alpha: float, *,
                block: int = 64 * 1024,
                interpret: Optional[bool] = None) -> jax.Array:
@@ -391,23 +417,12 @@ def per_scores(priorities: jax.Array, gumbel: jax.Array, alpha: float, *,
     (block_rows, 128) tiles. Under shard_map each group scores its local
     priority shard."""
     TRACE_COUNTS["per_scores"] += 1
-    (rows,) = priorities.shape
-    br = _block_rows(rows, block)
-    p2, g2 = _lane_dense(priorities, br), _lane_dense(gumbel, br)
-    spec = pl.BlockSpec((br, LANES), lambda b: (b, 0))
-    out = pl.pallas_call(
-        functools.partial(_per_scores_kernel, alpha=alpha),
-        grid=(p2.shape[0] // br,),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(p2.shape, jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(p2, g2)
-    return out.reshape(-1)[:rows]
+    out = _score_pass(priorities, gumbel, alpha, block, interpret)
+    return out.reshape(-1)[:priorities.shape[0]]
 
 
 # --------------------------------------------------------------------------- #
-# PER: score kernel + top-k selection (group-local index selection)
+# PER: score pass + threshold top-k (group-local index selection)
 # --------------------------------------------------------------------------- #
 
 # Index carried by top-k slots that hold no real row (score -inf). Among
@@ -446,12 +461,121 @@ def merge_topk_candidates(cand_scores: jax.Array, cand_idx: jax.Array,
     return v, jnp.take(cand_idx, pos), jnp.take(cand_pri, pos)
 
 
+# Exact top-k without sorting the window. ``lax.top_k`` with a k this
+# large lowers to a sort of every (score, index) pair of the window; the
+# selection below reads the window a fixed number of times instead and
+# sorts only the k survivors:
+#   1. keys: each score's float32 bits as an int32 whose signed order is
+#      the total order the TPU's top-k comparator applies to floats
+#      (-inf lowest, -0.0 below +0.0; ``_order_keys``);
+#   2. the k-th largest key ``t``, bit by bit from the top (one Pallas
+#      kernel, the keys resident in VMEM): ``count(key > t) < k <=
+#      count(key >= t)``;
+#   3. the k rows with key > t, or key == t among the first
+#      ``k - count(key > t)`` such rows in index order (top_k's stable
+#      tie rule), compacted to their ascending indices;
+#   4. ``top_k`` over those k scores orders them as the dense top-k does.
+
+_KTH_CHUNK = 128      # most rows of 128 lanes counted per loop step
+
+
+def _order_keys(x: jax.Array) -> jax.Array:
+    """float32 -> int32 key, monotone in signed order: the non-negative
+    floats keep their bits, the negative ones (sign bit set) have the
+    other 31 bits flipped. The map is its own inverse on int32 bits."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ 0x7FFFFFFF, b)
+
+
+def _key_scores(keys: jax.Array) -> jax.Array:
+    """Inverse of ``_order_keys``: the float32 scores, bit for bit."""
+    return jax.lax.bitcast_convert_type(_order_keys(keys), jnp.float32)
+
+
+def _kth_key_kernel(key_ref, out_ref, *, k: int, chunk: int):
+    """Bitwise search for the k-th largest key over the VMEM-resident
+    (R, LANES) keys: 32 counting passes, one per bit from the top, then
+    one more for ``count(key > t)``. Writes t to row 0 of the output
+    and that count to row 1."""
+    nc = key_ref.shape[0] // chunk
+
+    def count(pred):
+        def step(c, acc):
+            x = key_ref[pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :]
+            return acc + jnp.where(pred(x), 1, 0)
+        acc = jax.lax.fori_loop(0, nc, step,
+                                jnp.zeros((chunk, LANES), jnp.int32))
+        return jnp.sum(jnp.sum(acc, axis=0, keepdims=True), axis=1,
+                       keepdims=True)
+
+    def bit(b, t):
+        # t is the unsigned prefix found so far, moved to signed order by
+        # its sign bit; flipping bit 31 - b sets that bit of the prefix
+        cand = t ^ jax.lax.shift_left(jnp.int32(1), 31 - b)
+        return jnp.where(count(lambda x: x >= cand) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, bit,
+                          jnp.full((1, 1), -2**31, jnp.int32))
+    n_gt = count(lambda x: x > t)
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    out_ref[...] = jnp.where(row == 0, t, n_gt)
+
+
+def _kth_key(keys: jax.Array, k: int, interpret):
+    """-> (t, count(keys > t)) for the k-th largest of the (R, LANES)
+    keys, as int32 scalars."""
+    chunk = math.gcd(keys.shape[0], _KTH_CHUNK)
+    out = pl.pallas_call(
+        functools.partial(_kth_key_kernel, k=k, chunk=chunk),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_shape=jax.ShapeDtypeStruct((8, LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=keys.size * 4 + (8 << 20)),
+        interpret=resolve_interpret(interpret),
+    )(keys)
+    return out[0, 0], out[1, 0]
+
+
+def _lane_prefix(mask: jax.Array) -> jax.Array:
+    """Inclusive count of the set lanes up to each lane of a (R, LANES)
+    bool, as a matmul with a triangle of ones: 0/1 operands are exact at
+    any matmul precision, and the sums (<= 128) in float32."""
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1))
+    return jnp.dot(mask.astype(jnp.float32),
+                   tri.astype(jnp.float32)).astype(jnp.int32)
+
+
+def _top_rows(keys: jax.Array, t, n_gt, k: int) -> jax.Array:
+    """The k selected rows of the (R, LANES) keys -> their flat indices
+    (k,), ascending: every key > t, then the first ``k - n_gt`` keys
+    equal to t in index order. The compaction is two-level: a slot's
+    128-lane row comes from the rows' running counts, its lane from the
+    row's own running count."""
+    eq = keys == t
+    eq_row = jnp.sum(eq, axis=1, dtype=jnp.int32)
+    eq_before = jnp.cumsum(eq_row) - eq_row
+    sel = (keys > t) | (eq & (eq_before[:, None] + _lane_prefix(eq)
+                              <= k - n_gt))
+    count = jnp.sum(sel, axis=1, dtype=jnp.int32)
+    start = jnp.cumsum(count) - count          # first slot of each row
+    row = jax.lax.cummax(
+        jnp.zeros((k,), jnp.int32).at[jnp.where(count > 0, start, k)].max(
+            jnp.arange(keys.shape[0], dtype=jnp.int32), mode="drop"))
+    rank = jnp.arange(k, dtype=jnp.int32) - jnp.take(start, row)
+    lane = jnp.sum(jnp.take(_lane_prefix(sel), row, axis=0)
+                   <= rank[:, None], axis=1, dtype=jnp.int32)
+    return row * LANES + lane
+
+
 def per_topk(priorities: jax.Array, gumbel: jax.Array, alpha: float,
              k: int, *, window_start=0, block: int = 64 * 1024,
              interpret: Optional[bool] = None):
-    """PER selection over the (rows,) priority window: the ``per_scores``
-    kernel computes the Gumbel-top-k scores, XLA's ``top_k`` selects
-    (Pallas TPU has no ``top_k`` lowering).
+    """PER selection over the (rows,) priority window: the score pass
+    (``per_scores``' math, writing order keys), then the exact top-k by
+    threshold — a Pallas kernel finds the k-th largest key, XLA
+    compacts the k rows at or above it and sorts only those (the steps
+    above ``_order_keys``).
 
     Returns ``(scores (k,), global_idx (k,), priorities (k,))`` — the
     window's k best live candidates, indices offset by ``window_start``
@@ -464,8 +588,13 @@ def per_topk(priorities: jax.Array, gumbel: jax.Array, alpha: float,
     if k > rows:
         raise ValueError(f"per_topk of k={k} from a {rows}-row window")
     TRACE_COUNTS["per_topk"] += 1
-    v, i = jax.lax.top_k(per_scores(priorities, gumbel, alpha, block=block,
-                                    interpret=interpret), k)
+    TRACE_COUNTS["per_topk_threshold"] += 1
+    keys = _score_pass(priorities, gumbel, alpha, block, interpret,
+                       keys=True)
+    t, n_gt = _kth_key(keys, k, interpret)
+    idx = _top_rows(keys, t, n_gt, k)
+    v, pos = jax.lax.top_k(_key_scores(jnp.take(keys.reshape(-1), idx)), k)
+    i = jnp.take(idx, pos)
     gidx = i + jnp.asarray(window_start, jnp.int32)
     return (v, jnp.where(v > -jnp.inf, gidx, IDX_SENTINEL),
             jnp.take(priorities, i))

@@ -26,8 +26,8 @@ Two-phase selection (group-local PER): index selection routes through
 runs a top-k over its OWN priority shard and only ``(groups * k,)``
 candidates cross the batch axis for the merge; no globally assembled
 ``(capacity,)`` score vector exists. Under ``use_pallas`` the per-group
-pass is ``per_topk`` (the ``per_scores`` kernel + ``top_k``)
-shard_map'd over the mesh batch axes
+pass is ``per_topk`` (the score pass, then an exact top-k by threshold
+that sorts only the k survivors) shard_map'd over the mesh batch axes
 (``kernels.ops.per_topk_sharded``); the jnp oracle is the dense
 ``per_topk_ref`` (two-phase with a single group — bit-identical, since
 the merge in fixed group order with stable ties IS the dense top-k over
@@ -90,9 +90,9 @@ def _select(priorities: jax.Array, gumbel: jax.Array, alpha: float,
     priorities at the indices (k,)).
 
     Phase 1 is group-local: each batch group top-k's its own priority
-    shard (``per_topk``'s score kernel + top-k under ``use_pallas``, the
-    dense ``per_topk_ref`` oracle otherwise). Phase 2 merges the
-    ``(groups * k,)`` candidates in fixed group order
+    shard (``per_topk``'s score pass + threshold top-k under
+    ``use_pallas``, the dense ``per_topk_ref`` oracle otherwise). Phase
+    2 merges the ``(groups * k,)`` candidates in fixed group order
     (``merge_topk_candidates``) — with a single group the merge is the
     identity, so every mode computes the same dense top-k over live
     rows and PER draws are layout-invariant. ``"shard"`` requires each

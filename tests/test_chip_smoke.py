@@ -37,7 +37,29 @@ def test_one_chip_phases_at_tiny_size(no_kernel_check, capsys):
     phases = [json.loads(line)["smoke"]
               for line in capsys.readouterr().out.splitlines()]
     assert phases == ["a-uniform-pendulum", "b-per-nstep3-hopper",
-                      "c-pallas-vs-jnp-ring", "c-pallas-vs-jnp-per-hopper"]
+                      "c-pallas-vs-jnp-ring", "c-pallas-vs-jnp-per-hopper",
+                      "d-per-topk-vs-dense"]
+
+
+@pytest.mark.parametrize("fault", ["order", "tie"])
+def test_per_topk_phase_catches_a_wrong_selection(monkeypatch, fault):
+    """A selection that swaps two draws, or breaks a tie by the higher
+    index, must fail phase d."""
+    import jax.numpy as jnp
+
+    from repro.kernels import replay_ops as rops
+    per_topk = rops.per_topk
+
+    def wrong(p, g, alpha, k, **kw):
+        v, i, pri = per_topk(p, g, alpha, k, **kw)
+        if fault == "order":
+            return v, jnp.roll(i, 1), pri
+        # among equal scores, prefer the later row: reversed window
+        v, i, pri = per_topk(p[::-1], g[::-1], alpha, k, **kw)
+        return v, p.shape[0] - 1 - i, pri
+    monkeypatch.setattr(rops, "per_topk", wrong)
+    with pytest.raises(chip_smoke.SmokeFailure, match="differ"):
+        chip_smoke.per_topk_phase("d", TINY)
 
 
 def test_pallas_vs_jnp_catches_a_wrong_per_kernel(no_kernel_check,

@@ -1,5 +1,6 @@
-"""Group-local PER selection: ``per_topk`` (score kernel + top-k) vs the dense
-oracle (partial fill, ring-wrap layouts, window edges, k > live rows),
+"""Group-local PER selection: ``per_topk`` (score pass + threshold top-k)
+vs the dense oracle (partial fill, ties, signed zeros, ring-wrap layouts,
+window edges, k > live rows, k == rows), the key order against ``top_k``,
 the two-phase candidate merge, cross-mode/cross-layout determinism of
 PER draws, and the compiled-megastep probes (trace counts + no
 capacity-sized collective)."""
@@ -32,20 +33,68 @@ def _check_selection(got, want):
     np.testing.assert_array_equal(np.asarray(p)[fin], np.asarray(pr)[fin])
 
 
-@pytest.mark.parametrize("cap,live,k", [
-    (512, 512, 64),     # full pool
-    (512, 100, 64),     # partial fill
-    (300, 7, 32),       # k > live rows: -inf tail
-    (4096, 3, 16),      # mostly-empty (the PR-3 bug-class shape)
-])
-def test_per_topk_matches_dense_oracle(cap, live, k):
+def _window(cap, live, k, scores):
+    """A (cap,)-row priority window with ``live`` rows scattered through
+    it, its Gumbel noise and alpha, shaped so the scores are:
+    ``uniform`` — continuous; ``coarse`` — on a grid of 1/2, so runs of
+    ties straddle the k-th place; ``signed_zero`` — +0.0, -0.0 (alpha 0
+    times the log of a priority below 1) and a few positives;
+    ``negative`` — all below zero."""
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(cap + live + k), 3)
-    pri = jnp.where(jnp.arange(cap) < live,
-                    jax.random.uniform(k1, (cap,)) + 0.01, 0.0)
-    pri = pri[jax.random.permutation(k2, cap)]   # live rows scattered
+    u = jax.random.uniform(k1, (cap,))
     g = jax.random.gumbel(k3, (cap,))
-    _check_selection(rops.per_topk(pri, g, 0.6, k, block=128),
-                     rops.per_topk_ref(pri, g, 0.6, k))
+    alpha = 0.6
+    if scores == "coarse":
+        u, g = jnp.ones((cap,)), jnp.round(g * 2) / 2
+    elif scores == "signed_zero":
+        alpha, slot = 0.0, jnp.arange(cap)
+        u = jnp.where(slot % 2 == 0, 0.5, 2.0)
+        g = jnp.where(slot % 3 == 0, -0.0, jnp.where(slot % 7 == 1, 1.0, 0.0))
+    elif scores == "negative":
+        g = -jnp.abs(g) - 1.0
+    else:
+        u = u + 0.01
+    pri = jnp.where(jnp.arange(cap) < live, u, 0.0)
+    return pri[jax.random.permutation(k2, cap)], g, alpha
+
+
+@pytest.mark.parametrize("cap,live,k,scores", [
+    pytest.param(512, 512, 64, "uniform", id="512-512-64"),   # full pool
+    pytest.param(512, 100, 64, "uniform", id="512-100-64"),   # partial fill
+    pytest.param(300, 7, 32, "uniform", id="300-7-32"),       # k > live rows
+    pytest.param(4096, 3, 16, "uniform", id="4096-3-16"),     # mostly empty
+    (2048, 2048, 100, "coarse"),      # ties straddle the k-th place
+    (1000, 600, 800, "coarse"),       # ties, then the -inf tail
+    (4096, 4096, 1500, "signed_zero"),  # k-th place among the +0.0s
+    (4096, 4096, 3600, "signed_zero"),  # ... among the -0.0s below them
+    (512, 512, 512, "uniform"),       # k == rows
+    (640, 77, 640, "coarse"),         # k == rows, mostly -inf
+    (1024, 1024, 100, "negative"),    # every score below zero
+    (2000, 1500, 1800, "uniform"),    # fewer live rows than k
+    (16384, 16384, 128, "uniform"),   # the PER cell's rows / k ratio
+    (17000, 16000, 300, "uniform"),   # 136 lane rows: a ragged last chunk
+])
+def test_per_topk_matches_dense_oracle(cap, live, k, scores):
+    pri, g, alpha = _window(cap, live, k, scores)
+    _check_selection(rops.per_topk(pri, g, alpha, k, block=128),
+                     rops.per_topk_ref(pri, g, alpha, k))
+
+
+def test_order_keys_follow_top_k():
+    """The selection's int32 keys order floats as ``lax.top_k`` does:
+    -inf lowest, -0.0 below +0.0, subnormals in place. Sorting by key
+    (descending, stable) must give top_k's permutation."""
+    special = np.array([-np.inf, -3.4e38, -1.0, -1e-40, -0.0, 0.0, 1e-40,
+                        1.0, 3.4e38, np.inf], np.float32)
+    x = np.random.default_rng(0).permutation(np.repeat(special, 7))
+    keys = np.asarray(rops._order_keys(jnp.asarray(x)))
+    _, want = jax.lax.top_k(jnp.asarray(x), x.size)
+    np.testing.assert_array_equal(np.argsort(-keys.astype(np.int64),
+                                             kind="stable"),
+                                  np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(rops._key_scores(jnp.asarray(keys))).view(np.int32),
+        x.view(np.int32))
 
 
 def test_per_topk_ring_wrap_layout():
@@ -78,6 +127,22 @@ def test_per_topk_window_merge_equals_global():
     merged = rops.merge_topk_candidates(
         *(jnp.concatenate([c[j] for c in cand]) for j in range(3)), k)
     _check_selection(merged, rops.per_topk_ref(pri, g, 0.6, k))
+
+
+def test_per_megastep_selects_by_threshold():
+    """A single-device PER megastep with the kernels on traces the
+    threshold selection, not a dense top-k over the window."""
+    from repro.core import SpreezeConfig, SpreezeTrainer
+    cfg = SpreezeConfig(env_name="pendulum", algo="sac", num_envs=2,
+                        batch_size=32, chunk_len=4, updates_per_round=2,
+                        warmup_frames=32, replay_capacity=4096,
+                        eval_every_rounds=10**9, seed=3,
+                        rounds_per_dispatch=2, prioritized=True,
+                        use_pallas=True)
+    rops.reset_trace_counts()
+    tr = SpreezeTrainer(cfg)
+    tr._megastep.lower(tr.state, tr.replay, tr.env_states, tr.key)
+    assert rops.TRACE_COUNTS["per_topk_threshold"] > 0, rops.TRACE_COUNTS
 
 
 def test_per_topk_rejects_k_beyond_window():

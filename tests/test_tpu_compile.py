@@ -13,7 +13,9 @@ cache is off around the compiles — an entry compiled for a described
 chip cannot be read back on the CPU.
 """
 import contextlib
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,8 +91,21 @@ def test_ring_gather_compiles(one_chip, env):
 
 
 def test_per_topk_compiles(one_chip):
-    _compile(lambda p, g: rops.per_topk(p, g, 0.6, BATCH, interpret=False),
-             _spec(one_chip, (CAP,)), _spec(one_chip, (CAP,)))
+    """The threshold selection sorts only the k survivors: no ``sort``
+    in the program has an operand of CAP elements, and its temporaries
+    stay within a few CAP-sized 32-bit vectors."""
+    c = jax.jit(lambda p, g: rops.per_topk(p, g, 0.6, BATCH,
+                                           interpret=False)).lower(
+        _spec(one_chip, (CAP,)), _spec(one_chip, (CAP,))).compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    sorts = [line.split(" sort(")[0] for line in text.splitlines()
+             if " sort(" in line]
+    assert sorts
+    for shape in sorts:
+        for dims in re.findall(r"\[([\d,]+)\]", shape):
+            assert math.prod(map(int, dims.split(","))) < CAP, shape
+    assert c.memory_analysis().temp_size_in_bytes <= 4 * CAP * 4
 
 
 @pytest.mark.parametrize("window_start", [0, CAP])
