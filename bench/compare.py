@@ -26,6 +26,11 @@ leaf, whichever is larger. Leaves whose reference first moment is under
 a thousandth of the median leaf's are left out: Adam moves them by
 round-off alone. A cell compares the readings its ``limits/<cell>.json``
 names, each against its limit; the others are printed.
+
+Both sides hand over per-leaf float64 norms, not trees (``norms``,
+``change_norms``): each leaf is reduced as it comes off the device, so
+the host holds the trained leaves before the followed dispatches and
+about one leaf more, whatever the size of the model.
 """
 import numpy as np
 
@@ -38,29 +43,54 @@ NEGLIGIBLE = 1e-3
 WRITE_VALUE_ROWS = 8
 
 
-def leaves(tree, prefix=""):
-    """Flatten nested dicts and lists -> {path: float64 array}."""
+def paths(tree, prefix=""):
+    """Flatten nested dicts and lists -> [(path, leaf)], dict keys in
+    sorted order, list items by index, joined by dots."""
     if isinstance(tree, dict):
-        out = {}
-        for k in sorted(tree):
-            out.update(leaves(tree[k], f"{prefix}{k}."))
-        return out
+        return [x for k in sorted(tree)
+                for x in paths(tree[k], f"{prefix}{k}.")]
     if isinstance(tree, (list, tuple)):
-        out = {}
-        for i, v in enumerate(tree):
-            out.update(leaves(v, f"{prefix}{i}."))
-        return out
-    return {prefix[:-1]: np.asarray(tree, np.float64)}
+        return [x for i, v in enumerate(tree)
+                for x in paths(v, f"{prefix}{i}.")]
+    return [(prefix[:-1], tree)]
 
 
-def norms(tree):
-    return {k: float(np.linalg.norm(v.ravel()))
-            for k, v in leaves(tree).items()}
+def norm(x):
+    """The float64 norm of a leaf read back to the host."""
+    return float(np.linalg.norm(np.asarray(x, np.float64).ravel()))
+
+
+def norms(tree, fetch=np.asarray):
+    """{path: norm}, each leaf read back by ``fetch`` and reduced before
+    the next is read."""
+    return {k: norm(fetch(x)) for k, x in paths(tree)}
+
+
+def host_leaves(tree, fetch=np.asarray):
+    """{path: leaf} read back by ``fetch``, in the type it reads: the
+    trained leaves before the followed dispatches, the one tree held on
+    the host whole (the program's state is donated to each dispatch)."""
+    return {k: fetch(x) for k, x in paths(tree)}
+
+
+def change_norms(after, before, fetch=np.asarray):
+    """-> ({path: norm of the leaf}, {path: norm of its change since
+    ``before``, a ``host_leaves`` record}), one leaf at a time; the
+    leaves of ``before`` are dropped as they are used."""
+    before = dict(before)
+    params, change = {}, {}
+    for k, x in paths(after):
+        a = np.asarray(fetch(x), np.float64)
+        params[k] = norm(a)
+        change[k] = norm(a - np.asarray(before.pop(k), np.float64))
+    if before:
+        raise ValueError(f"leaves differ: {sorted(before)}")
+    return params, change
 
 
 def leaf_gaps(got, want, keep):
-    """{leaf: gap of norms over max(reference leaf norm, median)}"""
-    got, want = norms(got), norms(want)
+    """{leaf: gap of norms over max(reference leaf norm, median)} of two
+    ``norms`` records"""
     if set(got) != set(want):
         raise ValueError(f"leaves differ: {sorted(set(got) ^ set(want))}")
     med = float(np.median([want[k] for k in keep]))
@@ -70,14 +100,9 @@ def leaf_gaps(got, want, keep):
 
 def kept(ref):
     """Leaves whose reference gradient is not nought to rounding."""
-    m = norms(ref["first_moment"])
+    m = ref["first_moment"]
     med = float(np.median(list(m.values())))
     return sorted(k for k, v in m.items() if v >= NEGLIGIBLE * med)
-
-
-def change(run):
-    after, before = leaves(run["params"]), leaves(run["params0"])
-    return {k: after[k] - before[k] for k in after}
 
 
 def loss_gaps(prog, ref):
@@ -109,10 +134,10 @@ def relative(got, want):
 
 
 def readings(prog, ref):
-    """``prog`` and ``ref``: {"losses" (dispatches, rounds),
-    "first_moment", "second_moment", "params0", "params"} in the
-    reference's tree layout, and "priorities" after the first dispatch
-    under prioritized replay -> {name: number}."""
+    """``prog`` and ``ref``: {"losses" (dispatches, rounds), and the
+    per-leaf ``norms`` "first_moment", "second_moment", "params" and
+    "change", by the reference's leaf paths}, and "priorities" after the
+    first dispatch under prioritized replay -> {name: number}."""
     keep = kept(ref)
     losses = loss_gaps(prog, ref)
     out = {
@@ -122,7 +147,7 @@ def readings(prog, ref):
                                   ref["first_moment"], keep).values()),
         "second_moment_gap": max(leaf_gaps(
             prog["second_moment"], ref["second_moment"], keep).values()),
-        "change_gap": max(leaf_gaps(change(prog), change(ref),
+        "change_gap": max(leaf_gaps(prog["change"], ref["change"],
                                     keep).values()),
     }
     if ref.get("priorities") is not None:
@@ -147,16 +172,18 @@ def checks(values, limits):
 
 def detail(prog, ref):
     """What the readings are made of, for setting limits: each round's
-    loss gap, each leaf's gap of the first moment, the second moment and
-    the change, and the priority statistics of both sides."""
+    loss gap, each leaf's gap of the first moment, the second moment,
+    the change and the parameters after the last dispatch, and the
+    priority statistics of both sides."""
     keep = kept(ref)
     out = {"round_loss_gaps": loss_gaps(prog, ref).tolist(),
            "first_moment": leaf_gaps(prog["first_moment"],
                                      ref["first_moment"], keep),
            "second_moment": leaf_gaps(prog["second_moment"],
                                       ref["second_moment"], keep),
-           "change": leaf_gaps(change(prog), change(ref), keep),
-           "left_out": sorted(set(norms(ref["first_moment"])) - set(keep))}
+           "change": leaf_gaps(prog["change"], ref["change"], keep),
+           "params": leaf_gaps(prog["params"], ref["params"], keep),
+           "left_out": sorted(set(ref["first_moment"]) - set(keep))}
     if ref.get("priorities") is not None:
         out["priority_stats"] = {"program": priority_stats(
             prog["priorities"]), "reference": priority_stats(

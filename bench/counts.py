@@ -1,45 +1,28 @@
 """Operations and bytes a training cell needs, from its configuration's
 widths and its mix's sizes, and the table of device peaks.
 
-FLOPs count the matrix multiplications of the MLP towers (2 per
-multiply-add). One SAC update of one sampled row runs the actor 4 times
-and a Q tower 12 times, in forward-pass equivalents:
-- critic: actor forward on next_obs (1 actor); both target towers (2 Q);
-  both online towers forward and backward (2 + 4 Q);
-- actor: actor forward and backward (1 + 2 actor); both towers forward
-  and backward to the action only (2 + 2 Q).
-Each env frame adds one actor forward for the policy sample. Eval
-episodes are not training work and are not counted.
+The counts of a configuration's networks are its networks module's
+(``reference/networks_<name>.py``; ``networks_mlp.py`` counts the MLP
+towers): FLOPs per env frame and ring rows gathered per sample. A module
+without them gives no reading: the call raises, and never falls back to
+another module's count.
 """
 import json
 import os
 
+from reference import pipeline
+
 FLOAT32_BYTES = 4
 
 
-def mlp_macs(dims):
-    return sum(a * b for a, b in zip(dims[:-1], dims[1:]))
-
-
-def tower_macs(cfg):
-    """-> (actor MACs, one Q tower's MACs) per row."""
-    h = list(cfg["hidden"])
-    obs, act = cfg["obs_dim"], cfg["act_dim"]
-    return (mlp_macs([obs] + h + [2 * act]),
-            mlp_macs([obs + act] + h + [1]))
-
-
-def update_flops_per_sample(cfg):
-    actor, q = tower_macs(cfg)
-    return 2 * (4 * actor + 12 * q)
-
-
 def flops_per_env_frame(cfg, mix):
-    """Training FLOPs per env frame sampled: the policy forward plus the
-    updates, UTD (rows updated per frame) of them."""
-    utd = (mix["updates_per_round"] * mix["batch_size"]
-           / (mix["num_envs"] * mix["chunk_len"]))
-    return 2 * tower_macs(cfg)[0] + utd * update_flops_per_sample(cfg)
+    """Training FLOPs per env frame sampled, as the networks count them."""
+    return pipeline.networks(cfg).flops_per_env_frame(cfg, mix)
+
+
+def rows_per_sample(cfg, mix):
+    """Ring rows the training example of one sample gathers."""
+    return pipeline.networks(cfg).rows_per_sample(cfg, mix)
 
 
 def payload_bytes(cfg):

@@ -18,6 +18,7 @@ import sys
 import tempfile
 import time
 
+import compare
 from catalog import Catalog
 
 #: dispatches the reference follows from the seed
@@ -30,69 +31,119 @@ def log(*parts):
     print(*parts, file=sys.stderr, flush=True)
 
 
+#: configuration and mix keys that only the reference or the documents
+#: read; every other key names a field of SpreezeConfig or AlgoHP
+REFERENCE_ONLY = ("obs_dim", "act_dim", "episode_len", "dtype",
+                  "matmul_precision", "source", "description", "assumed",
+                  "deployment", "networks")
+#: keys that name a field by another name
+RENAMED = {"env": "env_name"}
+#: fields the harness sets itself, never a configuration
+HARNESS_SET = ("seed", "use_pallas", "hp")
+
+
+def _value(v):
+    return tuple(_value(x) for x in v) if isinstance(v, list) else v
+
+
 def program_config(cfg, mix, seed):
+    """The program's configuration of a cell, by field name: each key of
+    the configuration and of the mix goes to the field of that name of
+    SpreezeConfig, of AlgoHP, or of both (``env`` goes to ``env_name``),
+    JSON lists as tuples. The keys in REFERENCE_ONLY stay out; any other
+    key, or one of them that both files set, is an error that names it."""
+    import dataclasses
+
     from repro.core import SpreezeConfig
     from repro.rl.base import AlgoHP
-    return SpreezeConfig(
-        env_name=cfg["env"], algo=cfg["algo"],
-        replay_capacity=cfg["replay_capacity"],
-        num_envs=mix["num_envs"], chunk_len=mix["chunk_len"],
-        batch_size=mix["batch_size"],
-        updates_per_round=mix["updates_per_round"],
-        rounds_per_dispatch=mix["rounds_per_dispatch"],
-        warmup_frames=mix["warmup_frames"],
-        prioritized=mix["prioritized"],
-        per_alpha=mix.get("per_alpha", 0.6),
-        per_beta=mix.get("per_beta", 0.4), nstep=mix["nstep"],
-        eval_every_rounds=mix["eval_every_rounds"],
-        eval_episodes=mix["eval_episodes"],
-        use_pallas=True, seed=seed,
-        hp=AlgoHP(algo=cfg["algo"], gamma=cfg["gamma"], tau=cfg["tau"],
-                  lr=cfg["lr"], hidden=tuple(cfg["hidden"]),
-                  init_alpha=cfg["init_alpha"],
-                  target_entropy_scale=cfg["target_entropy_scale"]))
+    names = lambda cls: {f.name for f in dataclasses.fields(cls)}
+    top, hp_names = names(SpreezeConfig) - set(HARNESS_SET), names(AlgoHP)
+    top_kw, hp_kw, seen = {"seed": seed, "use_pallas": True}, {}, {}
+    for where, keys in (("configuration", cfg), ("traffic mix", mix)):
+        for key, value in keys.items():
+            if key in REFERENCE_ONLY:
+                continue
+            if key in seen:
+                raise ValueError(f"key {key!r} is set by both the "
+                                 f"{seen[key]} and the {where}")
+            seen[key] = where
+            name = RENAMED.get(key, key)
+            if key in HARNESS_SET or key in RENAMED.values() or (
+                    name not in top and name not in hp_names):
+                raise ValueError(
+                    f"{where} key {key!r} names no field of SpreezeConfig "
+                    f"or AlgoHP that a configuration may set")
+            for fields, kw in ((top, top_kw), (hp_names, hp_kw)):
+                if name in fields:
+                    kw[name] = _value(value)
+    return SpreezeConfig(**top_kw, hp=AlgoHP(**hp_kw))
 
 
-def _tower(params):
-    return [params[f"l{i}"] for i in range(len(params))]
+def _layout(tree):
+    """A program tree in the reference's layout: a dict whose keys are
+    ``l0`` .. ``l<n-1>`` becomes the list of its values; other dicts keep
+    their keys."""
+    if not isinstance(tree, dict):
+        return tree
+    if tree and set(tree) == {f"l{i}" for i in range(len(tree))}:
+        return [_layout(tree[f"l{i}"]) for i in range(len(tree))]
+    return {k: _layout(v) for k, v in tree.items()}
+
+
+def trained_fields(state):
+    """{trained field: its optimizer's field} of the program's state, by
+    one rule: a field ``opt_X`` holds the optimizer state of field ``X``,
+    or of ``log_X`` where the state has no ``X``. The reference names
+    the same leaves by the same field names."""
+    fields = state._fields
+    out = {}
+    for f in fields:
+        if f.startswith("opt_"):
+            x = f[len("opt_"):]
+            t = x if x in fields else f"log_{x}"
+            if t not in fields:
+                raise ValueError(f"state field {f!r} has no field {x!r} "
+                                 f"or {t!r} to train")
+            out[t] = f
+    return out
 
 
 def trained(state):
-    """The program's trained leaves, on the host, in the reference's
+    """The program's trained leaves, on the device, in the reference's
     layout."""
-    import jax
-    return jax.device_get({"actor": _tower(state.actor),
-                           "q": _tower(state.q),
-                           "log_alpha": state.log_alpha})
+    return {t: _layout(getattr(state, t)) for t in trained_fields(state)}
 
 
 def moments(state):
-    """Adam's first and second moments of the trained leaves."""
-    import jax
-    return jax.device_get((
-        {"actor": _tower(state.opt_actor.mu), "q": _tower(state.opt_q.mu),
-         "log_alpha": state.opt_alpha.mu},
-        {"actor": _tower(state.opt_actor.nu), "q": _tower(state.opt_q.nu),
-         "log_alpha": state.opt_alpha.nu}))
+    """Adam's first and second moments of the trained leaves, on the
+    device, in the reference's layout."""
+    opts = {t: getattr(state, f) for t, f in trained_fields(state).items()}
+    return ({t: _layout(o.mu) for t, o in opts.items()},
+            {t: _layout(o.nu) for t, o in opts.items()})
 
 
 def follow_program(tr, dispatches):
     """Warmup and ``dispatches`` dispatches, each one ``train()`` call
-    -> the readings ``compare.readings`` takes."""
+    -> the record ``compare.readings`` takes: per-leaf norms, each leaf
+    reduced as it comes off the device."""
     import numpy as np
     per_dispatch = tr.cfg.num_envs * tr.cfg.chunk_len * \
         tr.cfg.rounds_per_dispatch
     tr.train(max_frames=1)                        # the replay warmup
-    out = {"params0": trained(tr.state), "losses": []}
+    params0 = compare.host_leaves(trained(tr.state))
+    out = {"losses": []}
     for d in range(dispatches):
         tr.train(max_frames=tr.total_frames + per_dispatch)
         out["losses"].append(np.asarray(
             tr.last_metrics["critic_loss"]).tolist())
         if d == 0:
-            out["first_moment"], out["second_moment"] = moments(tr.state)
+            first, second = moments(tr.state)
+            out["first_moment"] = compare.norms(first)
+            out["second_moment"] = compare.norms(second)
             pri = getattr(tr.replay, "priorities", None)
             out["priorities"] = None if pri is None else np.asarray(pri)
-    out["params"] = trained(tr.state)
+    out["params"], out["change"] = compare.change_norms(trained(tr.state),
+                                                        params0)
     return out
 
 
@@ -134,7 +185,6 @@ def run_cell(cat, cell, cfg, mix, limits, seed, seconds, trace, devices,
     """Set-up, window and check of one cell -> the result dict."""
     import jax
 
-    import compare
     from repro.core import SpreezeTrainer
     from reference import pipeline
 

@@ -1,6 +1,7 @@
-"""The training step's share of the chips' bf16 peak: the MLP matmul
-FLOPs per env frame (``counts.flops_per_env_frame``) times the traced
-window's env frames per second, over chips times peak."""
+"""The training step's share of the chips' bf16 peak: the networks'
+matmul FLOPs per env frame (``counts.flops_per_env_frame``, from the
+configuration's networks module) times the traced window's env frames
+per second, over chips times peak."""
 import counts
 
 

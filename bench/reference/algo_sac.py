@@ -1,35 +1,19 @@
-"""Plain reference of SAC (Haarnoja et al., arXiv:1812.05905): a
-tanh-Gaussian actor and twin Q towers, both MLPs with ReLU, target
-towers by Polyak averaging, a learned temperature, Adam on each part.
+"""Plain reference of SAC (Haarnoja et al., arXiv:1812.05905): the
+losses of a tanh-Gaussian actor and twin Q towers, target copies by
+Polyak averaging, a learned temperature, Adam on each trained group.
 
-Every floating array is held in ``dtype`` (float32 for the reference,
-bfloat16 for the control); weights start as N(0, 1/fan_in), biases 0,
-log alpha at log(init_alpha). The key is split as the program splits
-it, so the same seed draws the same noise.
+The networks are a module's (``networks_mlp.py`` describes what one
+supplies): its ``GROUPS`` each get their own Adam state (``opt_<group>``),
+its ``CRITIC`` groups are trained by the critic loss and get a target
+copy (``<group>_target``), and the actor group by the actor loss. Every
+floating array is held in ``dtype`` (float32 for the reference, bfloat16
+for the control); log alpha starts at log(init_alpha). The key is split
+as the program splits it, so the same seed draws the same noise.
 """
-import math
-
 import jax
 import jax.numpy as jnp
 
-LOG_STD_MIN, LOG_STD_MAX = -20.0, 2.0
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
-
-
-def mlp_init(key, dims, dtype):
-    keys = jax.random.split(key, len(dims) - 1)
-    return [{"w": (jax.random.normal(keys[i], (dims[i], dims[i + 1]))
-                   * (1.0 / math.sqrt(dims[i]))).astype(dtype),
-             "b": jnp.zeros((dims[i + 1],), dtype)}
-            for i in range(len(dims) - 1)]
-
-
-def mlp(layers, x):
-    for i, l in enumerate(layers):
-        x = x @ l["w"] + l["b"]
-        if i < len(layers) - 1:
-            x = jnp.maximum(x, 0)
-    return x
 
 
 def adam_init(params):
@@ -52,75 +36,64 @@ def adam_step(params, grads, opt, lr):
     return new, {"t": t, "m": m, "v": v}
 
 
-def init(key, obs_dim, act_dim, hp, dtype):
-    ka, kq = jax.random.split(key)
-    h = list(hp["hidden"])
-    actor = mlp_init(ka, [obs_dim] + h + [2 * act_dim], dtype)
-    q = jax.vmap(lambda k: mlp_init(k, [obs_dim + act_dim] + h + [1],
-                                    dtype))(jax.random.split(kq, 2))
-    log_alpha = jnp.log(jnp.asarray(hp["init_alpha"], jnp.float32)
-                        ).astype(dtype)
-    return {"actor": actor, "q": q, "q_target": q, "log_alpha": log_alpha,
-            "opt_actor": adam_init(actor), "opt_q": adam_init(q),
-            "opt_alpha": adam_init(log_alpha)}
+def init(net, key, obs_dim, act_dim, hp, dtype):
+    s = dict(net.init(key, obs_dim, act_dim, hp, dtype))
+    s.update({f"{g}_target": s[g] for g in net.CRITIC})
+    s["log_alpha"] = jnp.log(jnp.asarray(hp["init_alpha"], jnp.float32)
+                             ).astype(dtype)
+    s.update({f"opt_{g}": adam_init(s[g]) for g in net.GROUPS})
+    s["opt_alpha"] = adam_init(s["log_alpha"])
+    return s
 
 
-def sample_action(actor, obs, key):
-    """-> (tanh-squashed action, its log-probability)"""
-    mean, log_std = jnp.split(mlp(actor, obs), 2, axis=-1)
-    log_std = jnp.clip(log_std, LOG_STD_MIN, LOG_STD_MAX)
-    eps = jax.random.normal(key, mean.shape).astype(mean.dtype)
-    a = jnp.tanh(mean + jnp.exp(log_std) * eps)
-    logp = (-0.5 * eps ** 2 - log_std - 0.5 * math.log(2 * math.pi)).sum(-1)
-    return a, logp - jnp.log(jnp.clip(1 - a ** 2, 1e-6)).sum(-1)
-
-
-def q_values(q, obs, act):
-    """-> (2, B): both towers"""
-    x = jnp.concatenate([obs, act], axis=-1)
-    return jax.vmap(lambda p: mlp(p, x)[..., 0])(q)
-
-
-def update(s, batch, key, hp, act_dim):
+def update(net, s, batch, key, hp, act_dim):
     """One SAC step on ``batch`` -> (state', critic loss, per-row |TD|)."""
     k1, k2 = jax.random.split(key)
     alpha = jnp.exp(s["log_alpha"])
-    na, nlogp = sample_action(s["actor"], batch["next_obs"], k1)
-    q_next = q_values(s["q_target"], batch["next_obs"], na).min(0)
+    na, nlogp = net.sample_action(s, batch["next_obs"], k1)
+    targets = dict(s, **{g: s[f"{g}_target"] for g in net.CRITIC})
+    q_next = net.q_values(targets, batch["next_obs"], na).min(0)
     target = jax.lax.stop_gradient(
         batch["rew"] + batch["disc"] * (q_next - alpha * nlogp))
     w = batch.get("weight")
 
-    def critic_loss(q):
-        err = q_values(q, batch["obs"], batch["act"]) - target[None]
+    def critic_loss(critic):
+        err = net.q_values(dict(s, **critic), batch["obs"], batch["act"]) \
+            - target[None]
         se = err ** 2 if w is None else err ** 2 * w[None]
         return se.mean(), jnp.abs(err).mean(0)
 
-    (closs, td), gq = jax.value_and_grad(critic_loss, has_aux=True)(s["q"])
-    q, opt_q = adam_step(s["q"], gq, s["opt_q"], hp["lr"])
+    (closs, td), grads = jax.value_and_grad(critic_loss, has_aux=True)(
+        {g: s[g] for g in net.CRITIC})
+    new = dict(s)
+    for g in net.CRITIC:
+        new[g], new[f"opt_{g}"] = adam_step(s[g], grads[g], s[f"opt_{g}"],
+                                            hp["lr"])
 
     def actor_loss(actor):
-        a, logp = sample_action(actor, batch["obs"], k2)
-        return (alpha * logp - q_values(q, batch["obs"], a).min(0)).mean(), \
-            logp.mean()
+        a, logp = net.sample_action(dict(new, actor=actor), batch["obs"], k2)
+        return (alpha * logp - net.q_values(new, batch["obs"], a).min(0)
+                ).mean(), logp.mean()
 
     (_, logp_mean), ga = jax.value_and_grad(actor_loss, has_aux=True)(
         s["actor"])
-    actor, opt_actor = adam_step(s["actor"], ga, s["opt_actor"], hp["lr"])
+    new["actor"], new["opt_actor"] = adam_step(s["actor"], ga,
+                                               s["opt_actor"], hp["lr"])
     # d/d(log alpha) of -log_alpha * (mean log pi + target entropy)
     g_alpha = (-(logp_mean - hp["target_entropy_scale"] * act_dim)
                ).astype(s["log_alpha"].dtype)
-    log_alpha, opt_alpha = adam_step(s["log_alpha"], g_alpha,
-                                     s["opt_alpha"], hp["lr"])
+    new["log_alpha"], new["opt_alpha"] = adam_step(
+        s["log_alpha"], g_alpha, s["opt_alpha"], hp["lr"])
     tau = hp["tau"]
-    q_target = jax.tree.map(lambda t, o: ((1 - tau) * t + tau * o
-                                          ).astype(t.dtype),
-                            s["q_target"], q)
-    return ({"actor": actor, "q": q, "q_target": q_target,
-             "log_alpha": log_alpha, "opt_actor": opt_actor, "opt_q": opt_q,
-             "opt_alpha": opt_alpha}, closs, td)
+    for g in net.CRITIC:
+        new[f"{g}_target"] = jax.tree.map(
+            lambda t, o: ((1 - tau) * t + tau * o).astype(t.dtype),
+            s[f"{g}_target"], new[g])
+    return new, closs, td
 
 
-#: the trained leaves whose change and first moment are compared
-TRAINED = ("actor", "q", "log_alpha")
-FIRST_MOMENT = {"actor": "opt_actor", "q": "opt_q", "log_alpha": "opt_alpha"}
+def first_moment(net):
+    """{trained leaves: the Adam state that holds their moments}: the
+    networks' groups and the temperature. These are the leaves whose
+    change and moments are compared."""
+    return {**{g: f"opt_{g}" for g in net.GROUPS}, "log_alpha": "opt_alpha"}

@@ -5,9 +5,10 @@ sampling, and the algorithm's update, in rounds and dispatches.
 It follows the program's data flow from the seed, with its own code:
 the same key splits draw the same env resets, policy noise and sample
 indices, so the reference and the program see the same experience up
-to rounding. Nothing here imports the program. The env and algorithm
-are found by the names in the configuration file:
-``env_<env>.py`` and ``algo_<algo>.py`` beside this file.
+to rounding. Nothing here imports the program. The env, the algorithm
+and the networks are found by the names in the configuration file:
+``env_<env>.py``, ``algo_<algo>.py`` and ``networks_<networks>.py``
+(``networks_mlp.py`` where the file names none) beside this file.
 """
 import importlib
 import math
@@ -16,9 +17,20 @@ import jax
 import jax.numpy as jnp
 
 
+#: the networks of a configuration that names none
+DEFAULT_NETWORKS = "mlp"
+
+
+def networks(cfg):
+    return importlib.import_module(
+        f"reference.networks_{cfg.get('networks', DEFAULT_NETWORKS)}")
+
+
 def modules(cfg):
+    """-> (env, algorithm, networks) modules of a configuration"""
     return (importlib.import_module(f"reference.env_{cfg['env']}"),
-            importlib.import_module(f"reference.algo_{cfg['algo']}"))
+            importlib.import_module(f"reference.algo_{cfg['algo']}"),
+            networks(cfg))
 
 
 def nstep_fold(rew, done, next_obs, n, gamma):
@@ -56,19 +68,19 @@ def make_run(cfg, mix, dtype=jnp.float32, fault=None):
     importance weights are all 1, or the rows are drawn uniformly."""
     if fault not in (None,) + FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
-    env, algo = modules(cfg)
+    env, algo, net = modules(cfg)
     N, T = mix["num_envs"], mix["chunk_len"]
     B, K, R = mix["batch_size"], mix["updates_per_round"], \
         mix["rounds_per_dispatch"]
     cap, gamma = cfg["replay_capacity"], cfg["gamma"]
     per = mix["prioritized"]
 
-    def chunk(actor, envs, key):
+    def chunk(s, rollout, key):
         def one(carry, _):
-            envs, key = carry
+            (envs, pstate), key = carry
             key, k_act, k_reset = jax.random.split(key, 3)
             obs = jax.vmap(env.observe)(envs)
-            a, _ = algo.sample_action(actor, obs.astype(dtype), k_act)
+            a, _, pstate = net.act(s, pstate, obs.astype(dtype), k_act)
             a = a.astype(jnp.float32)
 
             def env_step(s, a, k):
@@ -79,16 +91,18 @@ def make_run(cfg, mix, dtype=jnp.float32, fault=None):
 
             envs, nobs, r, d = jax.vmap(env_step)(
                 envs, a, jax.random.split(k_reset, N))
-            return (envs, key), (obs, a, r, nobs, d.astype(jnp.float32))
+            pstate = net.step_end(pstate, d)
+            return ((envs, pstate), key), (obs, a, r, nobs,
+                                           d.astype(jnp.float32))
 
-        (envs, key), (obs, act, rew, nobs, done) = jax.lax.scan(
-            one, (envs, key), None, length=T)
+        (rollout, key), (obs, act, rew, nobs, done) = jax.lax.scan(
+            one, (rollout, key), None, length=T)
         rew, nobs, disc = nstep_fold(rew, done, nobs, mix["nstep"], gamma)
         rows = {"obs": obs, "act": act, "rew": rew, "next_obs": nobs,
                 "done": done, "disc": disc}
         rows = {k: v.reshape((N * T,) + v.shape[2:]).astype(dtype)
                 for k, v in rows.items()}
-        return envs, rows, key
+        return rollout, rows, key
 
     def add(ring, rows):
         n = N * T
@@ -128,10 +142,11 @@ def make_run(cfg, mix, dtype=jnp.float32, fault=None):
                 w = None if w is None else w[:B // 2]
             if fault == "unit_weights":
                 w = jnp.ones_like(w)
-            batch = {k: v[idx] for k, v in ring["data"].items()}
+            batch = net.batch(ring["data"], idx, cfg, mix)
             if w is not None:
                 batch["weight"] = w.astype(dtype)
-            s, closs, td = algo.update(s, batch, k_upd, cfg, cfg["act_dim"])
+            s, closs, td = algo.update(net, s, batch, k_upd, cfg,
+                                       cfg["act_dim"])
             if per and fault != "skip_reprioritise":
                 new = jnp.abs(td).astype(jnp.float32) + 1e-3
                 ring = dict(ring, pri=ring["pri"].at[idx].set(new),
@@ -144,18 +159,21 @@ def make_run(cfg, mix, dtype=jnp.float32, fault=None):
 
     def dispatch(carry):
         def one_round(carry, _):
-            s, ring, envs, key = carry
-            envs, rows, key = chunk(s["actor"], envs, key)
+            s, ring, rollout, key = carry
+            rollout, rows, key = chunk(s, rollout, key)
             ring = add(ring, rows)
             s, ring, key, loss = update_round(s, ring, key)
-            return (s, ring, envs, key), loss
+            return (s, ring, rollout, key), loss
 
         return jax.lax.scan(one_round, carry, None, length=R)
 
     def init(key):
         key, k_algo, k_env, _ = jax.random.split(key, 4)
-        s = algo.init(k_algo, cfg["obs_dim"], cfg["act_dim"], cfg, dtype)
-        envs = jax.vmap(env.reset)(jax.random.split(k_env, N))
+        s = algo.init(net, k_algo, cfg["obs_dim"], cfg["act_dim"], cfg,
+                      dtype)
+        # each env's state and its policy's rollout state
+        rollout = (jax.vmap(env.reset)(jax.random.split(k_env, N)),
+                   net.policy_state(cfg, N, dtype))
         widths = {"obs": cfg["obs_dim"], "act": cfg["act_dim"],
                   "next_obs": cfg["obs_dim"]}
         ring = {"data": {k: jnp.zeros((cap, widths[k]) if k in widths
@@ -169,9 +187,9 @@ def make_run(cfg, mix, dtype=jnp.float32, fault=None):
             ring["max_pri"] = jnp.ones((), jnp.float32)
         # warmup: whole chunks under the initial policy, no updates
         for _ in range(math.ceil(mix["warmup_frames"] / (N * T))):
-            envs, rows, key = chunk(s["actor"], envs, key)
+            rollout, rows, key = chunk(s, rollout, key)
             ring = add(ring, rows)
-        return s, ring, envs, key
+        return s, ring, rollout, key
 
     def precise(fn):
         def wrapped(*a):
@@ -183,37 +201,37 @@ def make_run(cfg, mix, dtype=jnp.float32, fault=None):
         precise(jax.jit(dispatch, donate_argnums=0))
 
 
-def trained(s, algo):
-    return {k: s[k] for k in algo.TRAINED}
-
-
-def moments(s, algo):
-    """Adam's first and second moments of the trained leaves."""
-    return ({k: s[opt]["m"] for k, opt in algo.FIRST_MOMENT.items()},
-            {k: s[opt]["v"] for k, opt in algo.FIRST_MOMENT.items()})
-
-
 def follow(cfg, mix, seed, dispatches=3, dtype=jnp.float32, fault=None):
     """Run the reference through ``dispatches`` dispatches after the
     warmup -> {"losses": each dispatch's per-round critic losses,
-    "first_moment"/"second_moment": Adam's moments after dispatch 1,
+    "first_moment"/"second_moment": per-leaf norms of Adam's moments
+    after dispatch 1, "params"/"change": per-leaf norms of the trained
+    leaves after the last dispatch and of their change since the warmup,
     "priorities": the priority vector after dispatch 1 (None without
-    prioritized replay), "params0"/"params": the trained leaves before
-    dispatch 1 and after the last}, as host arrays."""
+    prioritized replay)}. The trained leaves are named by
+    ``algo.first_moment``; each leaf is read back in float32 and reduced
+    as it comes off the device."""
     import numpy as np
-    _, algo = modules(cfg)
+
+    import compare
+    _, algo, net = modules(cfg)
+    trained = algo.first_moment(net)
     init, dispatch = make_run(cfg, mix, dtype, fault)
     carry = init(jax.random.PRNGKey(seed))
-    to_host = lambda t: jax.tree.map(
-        lambda x: np.asarray(x.astype(jnp.float32)), t)
-    out = {"params0": to_host(trained(carry[0], algo)), "losses": []}
+    fetch = lambda x: np.asarray(x.astype(jnp.float32))
+    params0 = compare.host_leaves({k: carry[0][k] for k in trained}, fetch)
+    out = {"losses": []}
     for d in range(dispatches):
         carry, losses = dispatch(carry)
         out["losses"].append(np.asarray(losses).tolist())
         if d == 0:
-            out["first_moment"], out["second_moment"] = to_host(
-                moments(carry[0], algo))
+            s = carry[0]
+            out["first_moment"] = compare.norms(
+                {k: s[opt]["m"] for k, opt in trained.items()}, fetch)
+            out["second_moment"] = compare.norms(
+                {k: s[opt]["v"] for k, opt in trained.items()}, fetch)
             out["priorities"] = (np.asarray(carry[1]["pri"])
                                  if "pri" in carry[1] else None)
-    out["params"] = to_host(trained(carry[0], algo))
+    out["params"], out["change"] = compare.change_norms(
+        {k: carry[0][k] for k in trained}, params0, fetch)
     return out

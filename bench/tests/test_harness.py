@@ -153,3 +153,138 @@ def test_control_is_not_correct(name):
                               dtype=jnp.bfloat16)
     assert not compare.checks(compare.readings(control, ref), limits)[1]
     assert compare.checks(compare.readings(ref, ref), limits)[1]
+
+
+def legacy_program_config(cfg, mix, seed):
+    """The settings as the harness mapped them key by key before it
+    mapped them by field name."""
+    from repro.core import SpreezeConfig
+    from repro.rl.base import AlgoHP
+    return SpreezeConfig(
+        env_name=cfg["env"], algo=cfg["algo"],
+        replay_capacity=cfg["replay_capacity"],
+        num_envs=mix["num_envs"], chunk_len=mix["chunk_len"],
+        batch_size=mix["batch_size"],
+        updates_per_round=mix["updates_per_round"],
+        rounds_per_dispatch=mix["rounds_per_dispatch"],
+        warmup_frames=mix["warmup_frames"],
+        prioritized=mix["prioritized"],
+        per_alpha=mix.get("per_alpha", 0.6),
+        per_beta=mix.get("per_beta", 0.4), nstep=mix["nstep"],
+        eval_every_rounds=mix["eval_every_rounds"],
+        eval_episodes=mix["eval_episodes"],
+        use_pallas=True, seed=seed,
+        hp=AlgoHP(algo=cfg["algo"], gamma=cfg["gamma"], tau=cfg["tau"],
+                  lr=cfg["lr"], hidden=tuple(cfg["hidden"]),
+                  init_alpha=cfg["init_alpha"],
+                  target_entropy_scale=cfg["target_entropy_scale"]))
+
+
+@pytest.mark.parametrize("config,traffic", [
+    (c, t) for c in CAT.names("configs") for t in CAT.names("traffic")])
+def test_program_config_by_field_name_is_the_legacy_mapping(config,
+                                                            traffic):
+    """Every configuration under every mix builds the SpreezeConfig the
+    key-by-key mapping built, field for field."""
+    import dataclasses
+    cfg = CAT._json("configs", f"{config}.json")
+    mix = CAT._json("traffic", f"{traffic}.json")
+    got = harness.program_config(cfg, mix, 2**31 + 3)
+    want = legacy_program_config(cfg, mix, 2**31 + 3)
+    for f in dataclasses.fields(want):
+        assert getattr(got, f.name) == getattr(want, f.name), f.name
+    for f in dataclasses.fields(want.hp):
+        assert getattr(got.hp, f.name) == getattr(want.hp, f.name), f.name
+        assert type(getattr(got.hp, f.name)) is type(
+            getattr(want.hp, f.name)), f.name
+
+
+@pytest.mark.parametrize("where,key,value", [
+    ("config", "kv_lora_rank", 512), ("mix", "prefix_len", 4),
+    ("config", "seed", 1), ("mix", "use_pallas", False),
+    ("config", "env_name", "hopper"), ("mix", "lr", 1e-3)])
+def test_unknown_or_doubled_key_is_an_error_that_names_it(where, key,
+                                                          value):
+    """A key that names no field a configuration may set, or one that
+    the mix sets again, is refused, never dropped."""
+    _, cfg, mix, _ = CAT.workload(CELLS[0])
+    if where == "config":
+        cfg = dict(cfg, **{key: value})
+    else:
+        mix = dict(mix, **{key: value})
+    with pytest.raises(ValueError, match=repr(key)):
+        harness.program_config(cfg, mix, 1)
+
+
+def test_trained_leaves_follow_the_optimizer_fields():
+    """The trained subtrees are found by their optimizer fields, an
+    encoder with its own optimizer among them, and come out in the
+    reference's layout: ``l<i>`` dicts as lists."""
+    from typing import Any, NamedTuple
+
+    class Opt(NamedTuple):
+        step: Any
+        mu: Any
+        nu: Any
+
+    class State(NamedTuple):
+        actor: Any
+        q: Any
+        q_target: Any
+        encoder: Any
+        encoder_target: Any
+        log_alpha: Any
+        opt_actor: Any
+        opt_q: Any
+        opt_encoder: Any
+        opt_alpha: Any
+        step: Any
+
+    def tower(scale):
+        return {f"l{i}": {"w": jnp.full((2, 3), scale + i),
+                          "b": jnp.full((3,), -scale - i)}
+                for i in range(3)}
+
+    def enc(scale):
+        return {"embed": jnp.full((4,), scale),
+                "layers": {"l0": {"wkv": jnp.full((2,), scale)},
+                           "l1": {"wkv": jnp.full((2,), scale + 1)}}}
+
+    opt = lambda t: Opt(0, jax.tree.map(lambda x: x * 10, t),
+                        jax.tree.map(lambda x: x * 100, t))
+    params = {"actor": tower(1.0), "q": tower(2.0), "encoder": enc(3.0),
+              "log_alpha": jnp.asarray(0.5)}
+    state = State(q_target=tower(9.0), encoder_target=enc(9.0), step=7,
+                  opt_actor=opt(params["actor"]), opt_q=opt(params["q"]),
+                  opt_encoder=opt(params["encoder"]),
+                  opt_alpha=opt(params["log_alpha"]), **params)
+    assert harness.trained_fields(state) == {
+        "actor": "opt_actor", "q": "opt_q", "encoder": "opt_encoder",
+        "log_alpha": "opt_alpha"}
+    got = harness.trained(state)
+    want = {"actor": [{"w": jnp.full((2, 3), 1.0 + i),
+                       "b": jnp.full((3,), -1.0 - i)} for i in range(3)],
+            "q": [{"w": jnp.full((2, 3), 2.0 + i),
+                   "b": jnp.full((3,), -2.0 - i)} for i in range(3)],
+            "encoder": {"embed": jnp.full((4,), 3.0),
+                        "layers": [{"wkv": jnp.full((2,), 3.0)},
+                                   {"wkv": jnp.full((2,), 4.0)}]},
+            "log_alpha": jnp.asarray(0.5)}
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert all(bool((a == b).all()) for a, b in zip(
+        jax.tree.leaves(got), jax.tree.leaves(want)))
+    first, second = harness.moments(state)
+    assert jax.tree.structure(first) == jax.tree.structure(want)
+    assert all(bool((a == 10 * b).all()) for a, b in zip(
+        jax.tree.leaves(first), jax.tree.leaves(want)))
+    assert all(bool((a == 100 * b).all()) for a, b in zip(
+        jax.tree.leaves(second), jax.tree.leaves(want)))
+    paths = {k for k, _ in compare.paths(got)}
+    assert {"actor.2.w", "q.0.b", "encoder.layers.1.wkv", "encoder.embed",
+            "log_alpha"} <= paths and len(paths) == 16
+
+    class Orphan(NamedTuple):
+        actor: Any
+        opt_critic: Any
+    with pytest.raises(ValueError, match="opt_critic"):
+        harness.trained_fields(Orphan(1, 2))
